@@ -2,14 +2,16 @@
 
 Structural results (exact, hardware-independent): DMA descriptors issued
 per decode step with coalescing R=1 (per-page baseline) vs R=4/8, for
-contiguity-preserving vs fragmented allocators. Also times the
-interpret-mode kernel as a correctness-weighted proxy.
+contiguity-preserving vs fragmented allocators. Times the compiled kernel
+per call; the kernel only compiles for a TPU, so on any other backend
+the module fails instead of timing something else.
 """
 
 from __future__ import annotations
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -34,6 +36,10 @@ def make_tables(B, Pmax, P, fragmented: bool, rng):
 
 
 def main() -> list:
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        raise RuntimeError(f"paged-attention timing needs a TPU, found "
+                           f"{device.platform!r}")
     out = []
     rng = np.random.default_rng(0)
     B, H, Kh, D, T, Pmax = 4, 8, 4, 64, 16, 16
@@ -57,7 +63,8 @@ def main() -> list:
             out.append(csv_row(
                 f"paged_attention/{name}_R{R}", dt,
                 f"descriptors={stats['descriptors']};pages={stats['pages']};"
-                f"dma_reduction={stats['reduction']:.2f}x;maxerr={err:.1e}"))
+                f"dma_reduction={stats['reduction']:.2f}x;maxerr={err:.1e};"
+                f"device={device.device_kind}"))
     return out
 
 

@@ -21,8 +21,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro import compat  # noqa: F401  (jax.tree.flatten_with_path shim)
-
 PyTree = Any
 
 
@@ -42,6 +40,7 @@ class Checkpointer:
         self.dir.mkdir(parents=True, exist_ok=True)
         self.keep = keep
         self._thread: Optional[threading.Thread] = None
+        self._error: Optional[BaseException] = None   # the writer's failure
 
     # ---- save ------------------------------------------------------------
     def save(self, step: int, state: PyTree, extra: Optional[Dict] = None,
@@ -70,17 +69,27 @@ class Checkpointer:
             os.rename(tmp, final)                      # atomic publish
             self._gc()
 
+        def write_async():
+            try:
+                write()
+            except BaseException as e:  # noqa: BLE001 — handed to wait()
+                self._error = e
+
         if blocking:
             write()
         else:
             self.wait()
-            self._thread = threading.Thread(target=write, daemon=True)
+            self._thread = threading.Thread(target=write_async, daemon=True)
             self._thread.start()
 
     def wait(self) -> None:
+        """Join the async writer; re-raise what made its write fail."""
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        error, self._error = self._error, None
+        if error is not None:
+            raise error
 
     def _gc(self) -> None:
         steps = sorted(self.steps())

@@ -160,7 +160,7 @@ class RunConfig:
     remat: str = "none"                     # none | full | dots
     grad_compression: bool = False          # int8 + error feedback all-reduce
     checkpoint_every: int = 100
-    checkpoint_dir: str = "/tmp/repro_ckpt"
+    checkpoint_dir: str = "checkpoints"
     keep_checkpoints: int = 3
     seed: int = 0
 

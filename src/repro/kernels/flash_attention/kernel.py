@@ -2,10 +2,12 @@
 
 Grid (B, H, nq, nkv) — TPU iterates the minor-most axis sequentially, so
 the (m, l, acc) scratch persists across the nkv sweep for one (b, h, qi)
-output block. Causal blocks entirely in the future are SKIPPED with
-pl.when (no MXU work), recovering the ~2× triangular saving the pure-jnp
-reference wastes; sliding-window additionally skips blocks left of the
-window. BlockSpec tiling keeps VMEM at (q_block·D + 2·kv_block·D + acc).
+output block. The wrapper puts heads ahead of the sequence, so every
+block's last two dims are (block, D), the shape the TPU tiles. Causal
+blocks entirely in the future are SKIPPED with pl.when (no MXU work),
+recovering the ~2× triangular saving the pure-jnp reference wastes;
+sliding-window additionally skips blocks left of the window. BlockSpec
+tiling keeps VMEM at (q_block·D + 2·kv_block·D + acc).
 """
 
 from __future__ import annotations
@@ -38,9 +40,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
         jnp.int32, (q_block, kv_block), 1)
 
     def compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (qb, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (kb, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)                # (qb, D)
+        k = k_ref[0, 0].astype(jnp.float32)                # (kb, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         mask = kv_pos < skv
@@ -50,11 +52,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
             mask &= kv_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
         m_prev, l_prev = m_s[...], l_s[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_prev * corr + p.sum(axis=-1)
-        acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
+        l_s[...] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_s[...] = m_new
@@ -75,14 +77,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
 
     @pl.when(ki == num_kv - 1)
     def _():
-        out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     q_block: int = 256, kv_block: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B, Sq, H, D); k, v: (B, Skv, Kh, D). Returns (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
     _, Skv, Kh, _ = k.shape
@@ -97,24 +99,26 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _kernel, causal=causal, window=window, q_block=q_block,
         kv_block=kv_block, num_kv=nkv, sq=Sq, skv=Skv, scale=D ** -0.5)
 
-    return pl.pallas_call(
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))   # (B, heads, S, D)
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nkv),
         in_specs=[
-            pl.BlockSpec((1, q_block, 1, D),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, kv_block, 1, D),
-                         lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, kv_block, 1, D),
-                         lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
+            pl.BlockSpec((1, 1, q_block, D),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, kv_block, D),
+                         lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, kv_block, D),
+                         lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, q_block, 1, D),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, q_block, D),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((q_block,), jnp.float32),
-            pltpu.VMEM((q_block,), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
+    return jnp.swapaxes(out, 1, 2)

@@ -14,7 +14,7 @@ from .kernel import flash_attention
                                               "kv_block", "interpret"))
 def flash_attention_op(q, k, v, *, causal: bool = True,
                        window: Optional[int] = None, q_block: int = 256,
-                       kv_block: int = 256, interpret: bool = True):
+                       kv_block: int = 256, interpret: bool = False):
     return flash_attention(q, k, v, causal=causal, window=window,
                            q_block=q_block, kv_block=kv_block,
                            interpret=interpret)

@@ -57,8 +57,9 @@ def descriptor_stats(page_table: np.ndarray, pages_per_block: int) -> dict:
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
-def _call(q, kv_pages, block_start, block_valid, lengths, *,
-          pages_per_block: int, interpret: bool):
+def paged_attention_blocks(q, kv_pages, block_start, block_valid, lengths, *,
+                           pages_per_block: int, interpret: bool = False):
+    """The jitted kernel call on an already-planned block table."""
     return paged_attention_kernel(
         q, kv_pages, block_start, block_valid, lengths,
         pages_per_block=pages_per_block, interpret=interpret)
@@ -67,7 +68,7 @@ def _call(q, kv_pages, block_start, block_valid, lengths, *,
 def paged_attention(q: jax.Array, kv_pages: jax.Array,
                     page_table: np.ndarray, lengths: jax.Array,
                     *, pages_per_block: int = 4,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     starts, valid = plan_blocks(np.asarray(page_table), pages_per_block)
     # An R-page DMA may over-read up to R-1 pages past a run; a production
     # pool allocates R-1 slack pages at the end. Pad here so dynamic_slice
@@ -76,6 +77,7 @@ def paged_attention(q: jax.Array, kv_pages: jax.Array,
     if R > 1:
         pad = [(0, R - 1)] + [(0, 0)] * (kv_pages.ndim - 1)
         kv_pages = jax.numpy.pad(kv_pages, pad)
-    return _call(q, kv_pages, jax.numpy.asarray(starts),
-                 jax.numpy.asarray(valid), lengths,
-                 pages_per_block=pages_per_block, interpret=interpret)
+    return paged_attention_blocks(q, kv_pages, jax.numpy.asarray(starts),
+                                  jax.numpy.asarray(valid), lengths,
+                                  pages_per_block=pages_per_block,
+                                  interpret=interpret)

@@ -10,5 +10,6 @@ from .kernel import ssd_scan
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan_op(x, Bm, Cm, dt, A, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan_op(x, Bm, Cm, dt, A, *, chunk: int = 64,
+                interpret: bool = False):
     return ssd_scan(x, Bm, Cm, dt, A, chunk=chunk, interpret=interpret)

@@ -28,6 +28,8 @@ from repro.launch.steps import build_step                    # noqa: E402
 from repro.roofline.analysis import analyze, model_flops_for  # noqa: E402
 
 DRYRUN_ARCHS = [a for a in ARCH_IDS if a != "rdmabox-paper-100m"]
+# the production meshes are v5e pods; the host devices only stand in
+TARGET_KIND = "TPU v5 lite"
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str,
@@ -53,7 +55,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         rep = analyze(compiled, arch=arch, shape_name=shape_name,
                       mesh_name=mesh_kind, chips=chips,
                       model_flops=model_flops_for(cfg, shape),
-                      compile_seconds=dt)
+                      device_kind=TARGET_KIND, compile_seconds=dt)
         if hlo_dir is not None:
             path = Path(hlo_dir) / f"{arch}_{shape_name}_{mesh_kind}.hlo"
             path.write_text(compiled.as_text())

@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat  # noqa: F401  (backfills jax.set_mesh & co.)
-
 
 def _auto(n: int):
     return (jax.sharding.AxisType.Auto,) * n
@@ -22,11 +20,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / examples)."""
+    """Mesh over the first data·model local devices."""
     return jax.make_mesh((data, model), ("data", "model"), axis_types=_auto(2))
 
 
-# TPU v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link
+def device_info() -> dict:
+    """The device a run lands on, as JAX reports it."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+

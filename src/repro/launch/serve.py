@@ -1,9 +1,11 @@
 """Batched serving driver: prefill → decode against the paged KV tier.
 
-Demonstrates the full serving path on CPU: contiguous-cache decode for the
-jitted model step, while the host-side PagedKVCache (+ RDMAbox remote
-spill) manages per-sequence KV pages with run-coalesced gathers — the
-paper's node-level abstraction serving an LLM.
+Runs the full serving path: contiguous-cache decode for the jitted model
+step, while the host-side PagedKVCache (+ RDMAbox remote spill) manages
+per-sequence KV pages with run-coalesced gathers — the paper's node-level
+abstraction serving an LLM. Prefill and the decode step are compiled
+before the timed windows; the device they ran on is printed with the
+rates.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced \
       --batch 4 --prompt-len 64 --gen 32
@@ -13,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +23,8 @@ import numpy as np
 
 from repro import box
 from repro.configs import get_config, get_reduced
-from repro.launch.mesh import make_local_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import device_info, make_local_mesh
 from repro.models import decode_step, init_cache, init_stack, prefill
 
 # pages reserved per client for the KV spill arena (the heap slice of
@@ -28,7 +32,10 @@ from repro.models import decode_step, init_cache, init_stack, prefill
 KV_HEAP_PAGES = 1024
 
 
-def main() -> None:
+def run(argv: Optional[Sequence[str]] = None) -> Dict:
+    """Serve one batch; returns timings, the device, the first decode
+    step's logits and what the reference check needs (params, prompts,
+    the first generated token, the config)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -52,7 +59,7 @@ def main() -> None:
                     help="per-link bandwidth cap (default: NIC port only)")
     ap.add_argument("--straggler", type=str, default=None, metavar="NODE:X",
                     help="make donor NODE a straggler with latency xX")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     fabric_flags = (args.straggler is not None or args.link_gbps is not None
                     or args.link_latency_us != 1.0 or args.donors != 2
@@ -70,32 +77,53 @@ def main() -> None:
             ap.error(f"--straggler expects NODE:FACTOR (e.g. 1:30), "
                      f"got {args.straggler!r}")
 
+    use_compile_cache()
+    device = device_info()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_local_mesh(1, 1)
     B, S = args.batch, args.prompt_len + args.gen
     rng = np.random.default_rng(0)
+    out: Dict = {"device": device, "cfg": cfg}
+    print(f"serve arch={cfg.name} on {device['platform']} {device['kind']} "
+          f"x{device['count']}", flush=True)
+
+    def pick(logits, tok):
+        # greedy next token; embedding-frontend archs feed their input on
+        if cfg.frontend:
+            return tok
+        return jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+
+    def serve_step(p, c, t, i):
+        logits, c = decode_step(p, c, t, i, cfg)
+        return logits, c, pick(logits, t), i + 1
 
     with jax.set_mesh(mesh):
         params, _ = init_stack(jax.random.key(0), cfg)
         if cfg.frontend:
             prompts = jnp.asarray(
                 rng.normal(size=(B, args.prompt_len, cfg.d_model)), jnp.bfloat16)
+            tok = jnp.asarray(rng.normal(size=(B, cfg.d_model)), jnp.bfloat16)
         else:
             prompts = jnp.asarray(
                 rng.integers(0, cfg.vocab_size, (B, args.prompt_len)), jnp.int32)
-
-        # prefill gives last-token logits + a prompt-length cache; decode
-        # needs a full-length cache: allocate and splice the prefill cache in.
-        t0 = time.perf_counter()
-        logits, pcache = jax.jit(
-            lambda p, t: prefill(p, t, cfg))(params, prompts)
+            tok = jnp.zeros((B,), jnp.int32)
+        cur = jnp.full((B,), args.prompt_len, jnp.int32)
         cache = init_cache(cfg, B, max_len=S)
 
-        def splice(full, part):
-            if full.ndim >= 3 and part.shape[2:] == full.shape[2:] and \
-                    part.shape[1] <= full.shape[1]:
-                return full.at[:, :part.shape[1]].set(part.astype(full.dtype))
-            return part.astype(full.dtype)
+        # compile outside the timed windows
+        t0 = time.perf_counter()
+        prefill_fn = jax.jit(lambda p, t: prefill(p, t, cfg)).lower(
+            params, prompts).compile()
+        step_fn = jax.jit(serve_step).lower(params, cache, tok, cur).compile()
+        out["compile_s"] = time.perf_counter() - t0
+        print(f"compile prefill+decode: {out['compile_s']:.2f}s", flush=True)
+
+        # prefill gives last-token logits + a prompt-length cache; decode
+        # needs a full-length cache: splice the prefill cache in.
+        t0 = time.perf_counter()
+        logits, pcache = prefill_fn(params, prompts)
+        jax.block_until_ready(pcache)
+        out["prefill_s"] = time.perf_counter() - t0
 
         def splice_leaf(full, part):
             # cache leaves are stacked (L, B, ...); match on trailing dims
@@ -107,8 +135,10 @@ def main() -> None:
             return part.astype(full.dtype)
 
         cache = jax.tree.map(splice_leaf, cache, pcache)
+        tok = pick(logits, tok)
+        out.update(params=params, prompts=prompts, first_token=tok)
         print(f"prefill {args.prompt_len} tokens × {B} seqs in "
-              f"{time.perf_counter()-t0:.2f}s")
+              f"{out['prefill_s']:.3f}s", flush=True)
 
         # host-side paged KV tier mirrors the device cache per sequence
         kv_features = 64
@@ -131,27 +161,27 @@ def main() -> None:
             for b in range(B):
                 paged.add_sequence(b)
 
-        step_fn = jax.jit(lambda p, c, t, i: decode_step(p, c, t, i, cfg))
-        if cfg.frontend:
-            tok = jnp.asarray(rng.normal(size=(B, cfg.d_model)), jnp.bfloat16)
-        else:
-            tok = jnp.argmax(logits[:, : cfg.vocab_size], axis=-1).astype(jnp.int32)
-        cur = jnp.full((B,), args.prompt_len, jnp.int32)
         out_tokens = []
+        first_logits = None
         t0 = time.perf_counter()
         for i in range(args.gen):
-            logits, cache = step_fn(params, cache, tok, cur)
+            logits, cache, tok, cur = step_fn(params, cache, tok, cur)
+            if i == 0:
+                first_logits = logits
             if not cfg.frontend:
-                tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
                 out_tokens.append(np.asarray(tok))
-            cur = cur + 1
             if paged is not None:
                 kv_rows = rng.normal(size=(B, kv_features)).astype(np.float32)
                 for b in range(B):
                     paged.append_tokens(b, kv_rows[b : b + 1])
+        jax.block_until_ready(cache)
         dt = time.perf_counter() - t0
+        out["decode_tok_s"] = args.gen * B / dt
+        if first_logits is not None:
+            out["first_logits"] = np.asarray(first_logits, np.float32)
         print(f"decode {args.gen} steps × {B} seqs: "
-              f"{args.gen*B/dt:,.1f} tok/s")
+              f"{out['decode_tok_s']:,.1f} tok/s on {device['platform']} "
+              f"{device['kind']}", flush=True)
         if out_tokens:
             arr = np.stack(out_tokens, axis=1)
             print("sample continuation token ids:", arr[0, :16].tolist())
@@ -184,8 +214,18 @@ def main() -> None:
                               for i in range(1, args.clients)]
                 for t in bg_threads:
                     t.start()
-            paged.spill(0)
-            paged.fetch(0)
+            # every sequence's pages round-trip through the donors and
+            # must come back byte-for-byte
+            before = [paged.gather(b).copy() for b in range(B)]
+            for b in range(B):
+                paged.spill(b)
+            for b in range(B):
+                paged.fetch(b)
+            out["spill_exact"] = all(
+                np.array_equal(paged.gather(b).view(np.uint8),
+                               before[b].view(np.uint8)) for b in range(B))
+            print(f"spill/fetch of {B} sequences byte-exact: "
+                  f"{out['spill_exact']}", flush=True)
             for t in bg_threads:
                 t.join()
             st = session.stats()
@@ -201,6 +241,13 @@ def main() -> None:
                       st["fabric"]["service"])
             session.close()
         print("SERVING DONE")
+    return out
+
+
+def main() -> None:
+    out = run()
+    if out.get("spill_exact") is False:
+        raise SystemExit("spilled KV pages came back changed")
 
 
 if __name__ == "__main__":

@@ -16,9 +16,29 @@ import re
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-PEAK_FLOPS_BF16 = 197e12     # TPU v5e per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclass(frozen=True)
+class Peaks:
+    flops_bf16: float            # FLOP/s per chip
+    hbm_bw: float                # bytes/s per chip
+    ici_bw: float                # bytes/s per link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s interchip interconnect over 4 links).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Peaks of ``device_kind``; a kind not in the table is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -81,20 +101,25 @@ class RooflineReport:
     hlo_bytes: float                 # per device
     coll_bytes: Dict[str, int]       # per device, by category
     model_flops: float               # 6·N·D (global, analytic)
+    device_kind: str                 # key into PEAKS
     memory_stats: Optional[Dict] = None
     compile_seconds: float = 0.0
 
     @property
+    def peaks(self) -> Peaks:
+        return peaks_for(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS_BF16
+        return self.hlo_flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return sum(self.coll_bytes.values()) / ICI_BW
+        return sum(self.coll_bytes.values()) / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -121,13 +146,13 @@ class RooflineReport:
         best take ``bound_s``, so this is the MFU the compiled program could
         reach if it hit its own roofline.
         """
-        ideal = self.model_flops / (self.chips * PEAK_FLOPS_BF16)
+        ideal = self.model_flops / (self.chips * self.peaks.flops_bf16)
         return ideal / self.bound_s if self.bound_s else 0.0
 
     def to_dict(self) -> Dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "hlo_flops": self.hlo_flops, "hlo_bytes": self.hlo_bytes,
             "coll_bytes": self.coll_bytes, "model_flops": self.model_flops,
             "compute_s": self.compute_s, "memory_s": self.memory_s,
@@ -155,7 +180,7 @@ def model_flops_for(cfg, shape) -> float:
 
 
 def analyze(compiled, *, arch: str, shape_name: str, mesh_name: str,
-            chips: int, model_flops: float,
+            chips: int, model_flops: float, device_kind: str,
             compile_seconds: float = 0.0) -> RooflineReport:
     """Roofline terms via the loop-aware HLO analyzer (hlo_parse).
 
@@ -190,5 +215,5 @@ def analyze(compiled, *, arch: str, shape_name: str, mesh_name: str,
     return RooflineReport(
         arch=arch, shape=shape_name, mesh=mesh_name, chips=chips,
         hlo_flops=flops, hlo_bytes=byts, coll_bytes=colls,
-        model_flops=model_flops, memory_stats=mem,
+        model_flops=model_flops, device_kind=device_kind, memory_stats=mem,
         compile_seconds=compile_seconds)

@@ -10,5 +10,3 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # repo root too, so tests can import the benchmark helpers
 # (benchmarks.common's zipfian generators have their own unit tests)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-import repro.compat  # noqa: E402,F401  (JAX version shims before any test)

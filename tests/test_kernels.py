@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels.flash_attention.ops import flash_attention_op
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.paged_attention.kernel import heads_per_chunk
 from repro.kernels.paged_attention.ops import (descriptor_stats,
                                                paged_attention, plan_blocks)
 from repro.kernels.paged_attention.ref import paged_attention_ref
@@ -32,7 +33,7 @@ def test_flash_vs_ref(Sq, Skv, H, Kh, D, causal, window, qb, kb, dtype):
     k = jnp.asarray(RNG.normal(size=(2, Skv, Kh, D)), dtype)
     v = jnp.asarray(RNG.normal(size=(2, Skv, Kh, D)), dtype)
     out = flash_attention_op(q, k, v, causal=causal, window=window,
-                             q_block=qb, kv_block=kb)
+                             q_block=qb, kv_block=kb, interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -65,11 +66,38 @@ def test_paged_attention_vs_ref(R, contig, dtype):
     table = _random_table(B, Pmax, P, contiguous=contig)
     npages = (table >= 0).sum(1)
     lengths = jnp.asarray(npages * T - RNG.integers(0, T, B), jnp.int32)
-    out = paged_attention(q, kv, table, lengths, pages_per_block=R)
+    out = paged_attention(q, kv, table, lengths, pages_per_block=R,
+                          interpret=True)
     ref = paged_attention_ref(q, kv, jnp.asarray(table), lengths)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("H,Kh,D", [
+    (16, 16, 64),      # qwen1.5-0.5b: two heads share a 128-lane chunk
+    (12, 4, 64),       # rdmabox-paper-100m: GQA, G=3 query rows per head
+    (8, 2, 128),       # one head per chunk
+])
+def test_paged_attention_lane_packing_vs_ref(H, Kh, D):
+    B, T, P, Pmax = 2, 16, 24, 5
+    q = jnp.asarray(RNG.normal(size=(B, H, D)), jnp.float32)
+    kv = jnp.asarray(RNG.normal(size=(P, T, 2, Kh, D)), jnp.float32)
+    table = _random_table(B, Pmax, P)
+    lengths = jnp.asarray((table >= 0).sum(1) * T - 3, jnp.int32)
+    out = paged_attention(q, kv, table, lengths, pages_per_block=2,
+                          interpret=True)
+    ref = paged_attention_ref(q, kv, jnp.asarray(table), lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_heads_per_chunk_rejects_unpackable_widths():
+    assert heads_per_chunk(64, 16) == 2 and heads_per_chunk(256, 3) == 1
+    with pytest.raises(ValueError):
+        heads_per_chunk(48, 8)          # 128 is not a multiple of 48
+    with pytest.raises(ValueError):
+        heads_per_chunk(32, 2)          # 4 heads per chunk, only 2 exist
 
 
 def test_planner_coalesces_contiguous():
@@ -101,7 +129,7 @@ def test_ssd_vs_ref(B, L, H, P, N, chunk):
     Cm = jnp.asarray(RNG.normal(size=(B, L, N)), jnp.float32) * 0.5
     dt = jnp.asarray(RNG.uniform(0.01, 0.2, size=(B, L, H)), jnp.float32)
     A = -jnp.asarray(RNG.uniform(0.5, 2.0, size=(H,)), jnp.float32)
-    out = ssd_scan_op(x, Bm, Cm, dt, A, chunk=chunk)
+    out = ssd_scan_op(x, Bm, Cm, dt, A, chunk=chunk, interpret=True)
     ref = ssd_ref(x, Bm, Cm, dt, A)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
@@ -115,6 +143,6 @@ def test_ssd_state_continuity_across_chunks():
     Cm = jnp.asarray(RNG.normal(size=(B, L, N)), jnp.float32) * 0.5
     dt = jnp.asarray(RNG.uniform(0.01, 0.2, size=(B, L, H)), jnp.float32)
     A = -jnp.ones((H,), jnp.float32)
-    a = ssd_scan_op(x, Bm, Cm, dt, A, chunk=16)
-    b = ssd_scan_op(x, Bm, Cm, dt, A, chunk=128)
+    a = ssd_scan_op(x, Bm, Cm, dt, A, chunk=16, interpret=True)
+    b = ssd_scan_op(x, Bm, Cm, dt, A, chunk=128, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)

@@ -4,13 +4,14 @@ import pytest
 
 from repro.configs import SHAPES, get_config
 from repro.core import RegMode, resolve_reg_mode
-from repro.roofline.analysis import RooflineReport, model_flops_for
+from repro.roofline.analysis import (RooflineReport, model_flops_for,
+                                     peaks_for)
 
 
 def _rep(**kw):
     base = dict(arch="a", shape="s", mesh="single", chips=256,
                 hlo_flops=197e12, hlo_bytes=819e9, coll_bytes={"all-reduce": 50e9},
-                model_flops=197e12 * 256)
+                model_flops=197e12 * 256, device_kind="TPU v5 lite")
     base.update(kw)
     return RooflineReport(**base)
 
@@ -23,6 +24,14 @@ def test_roofline_terms_unit():
     assert r.bound_s == pytest.approx(1.0)
     assert r.roofline_fraction == pytest.approx(1.0)
     assert r.useful_flops_ratio == pytest.approx(1.0)
+
+
+def test_peaks_unknown_device_kind_raises():
+    assert peaks_for("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
+    with pytest.raises(ValueError):
+        _rep(device_kind="cpu").compute_s
 
 
 def test_dominant_term():

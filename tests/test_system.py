@@ -4,6 +4,7 @@ optimizer, data determinism, HLO analyzer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import RunConfig, get_reduced
@@ -72,6 +73,36 @@ def test_checkpoint_gc_keeps_n(tmp_path):
     for s in (1, 2, 3, 4):
         ck.save(s, state)
     assert ck.steps() == [3, 4]
+
+
+def test_checkpoint_async_write_failure_raises_on_wait(tmp_path, monkeypatch):
+    ck = Checkpointer(str(tmp_path))
+
+    def disk_full(*_a, **_k):
+        raise OSError("disk full")
+
+    monkeypatch.setattr(np, "save", disk_full)
+    ck.save(1, {"w": jnp.ones((2,))}, blocking=False)
+    with pytest.raises(OSError, match="disk full"):
+        ck.wait()
+    ck.wait()                    # the error is reported once
+    assert ck.steps() == []
+
+
+def test_compile_cache_env_dir_wins_else_checkout(monkeypatch):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # nothing set
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (compile_cache.CHECKOUT / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_checkpoint_restores_dtypes(tmp_path):
