@@ -461,4 +461,9 @@ class KVStore(PagedKVCache, _Capability):
             "gather_descriptors": self.gather_descriptors,
             "gather_pages": self.gather_pages,
             "fragmentation": self.alloc.fragmentation(),
+            "rows_appended": self.rows_appended,
+            "pages_spilled": self.pages_spilled,
+            "bytes_spilled": self.bytes_spilled,
+            "pages_fetched": self.pages_fetched,
+            "bytes_fetched": self.bytes_fetched,
         }

@@ -5,7 +5,8 @@ step, while the host-side PagedKVCache (+ RDMAbox remote spill) manages
 per-sequence KV pages with run-coalesced gathers — the paper's node-level
 abstraction serving an LLM. Prefill and the decode step are compiled
 before the timed windows; the device they ran on is printed with the
-rates.
+rates. Each layer boundary of a job is a span of ``repro.trace``; the
+job's summary of its spans and counters is printed at its end.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced \
       --batch 4 --prompt-len 64 --gen 32
@@ -14,14 +15,13 @@ rates.
 from __future__ import annotations
 
 import argparse
-import time
 from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import box
+from repro import box, trace
 from repro.configs import get_config, get_reduced
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import device_info, make_local_mesh
@@ -30,6 +30,9 @@ from repro.models import decode_step, init_cache, init_stack, prefill
 # pages reserved per client for the KV spill arena (the heap slice of
 # each donor region); the rest of the slice backs background paging
 KV_HEAP_PAGES = 1024
+# the KV tier's counters (its snapshot) that a job's record takes
+KV_COUNTERS = ("rows_appended", "pages_spilled", "bytes_spilled",
+               "pages_fetched", "bytes_fetched")
 
 
 def run(argv: Optional[Sequence[str]] = None) -> Dict:
@@ -93,37 +96,46 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
             return tok
         return jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
 
+    def serve_prefill(p, t):
+        return prefill(p, t, cfg)
+
     def serve_step(p, c, t, i):
         logits, c = decode_step(p, c, t, i, cfg)
         return logits, c, pick(logits, t), i + 1
 
-    with jax.set_mesh(mesh):
-        params, _ = init_stack(jax.random.key(0), cfg)
-        if cfg.frontend:
-            prompts = jnp.asarray(
-                rng.normal(size=(B, args.prompt_len, cfg.d_model)), jnp.bfloat16)
-            tok = jnp.asarray(rng.normal(size=(B, cfg.d_model)), jnp.bfloat16)
-        else:
-            prompts = jnp.asarray(
-                rng.integers(0, cfg.vocab_size, (B, args.prompt_len)), jnp.int32)
-            tok = jnp.zeros((B,), jnp.int32)
-        cur = jnp.full((B,), args.prompt_len, jnp.int32)
-        cache = init_cache(cfg, B, max_len=S)
+    # the job's spans (repro.trace) time its windows: prefill_s,
+    # compile_s and decode_tok_s are read from them
+    with trace.job("serve.job") as root, jax.set_mesh(mesh):
+        with trace.span("serve.init"):
+            params, _ = init_stack(jax.random.key(0), cfg)
+            if cfg.frontend:
+                prompts = jnp.asarray(
+                    rng.normal(size=(B, args.prompt_len, cfg.d_model)),
+                    jnp.bfloat16)
+                tok = jnp.asarray(rng.normal(size=(B, cfg.d_model)),
+                                  jnp.bfloat16)
+            else:
+                prompts = jnp.asarray(
+                    rng.integers(0, cfg.vocab_size, (B, args.prompt_len)),
+                    jnp.int32)
+                tok = jnp.zeros((B,), jnp.int32)
+            cur = jnp.full((B,), args.prompt_len, jnp.int32)
+            cache = init_cache(cfg, B, max_len=S)
 
         # compile outside the timed windows
-        t0 = time.perf_counter()
-        prefill_fn = jax.jit(lambda p, t: prefill(p, t, cfg)).lower(
-            params, prompts).compile()
-        step_fn = jax.jit(serve_step).lower(params, cache, tok, cur).compile()
-        out["compile_s"] = time.perf_counter() - t0
+        with trace.span("serve.compile") as compiling:
+            prefill_fn = jax.jit(serve_prefill).lower(params, prompts).compile()
+            step_fn = jax.jit(serve_step).lower(params, cache, tok,
+                                                cur).compile()
+        out["compile_s"] = trace.seconds(compiling)
         print(f"compile prefill+decode: {out['compile_s']:.2f}s", flush=True)
 
         # prefill gives last-token logits + a prompt-length cache; decode
         # needs a full-length cache: splice the prefill cache in.
-        t0 = time.perf_counter()
-        logits, pcache = prefill_fn(params, prompts)
-        jax.block_until_ready(pcache)
-        out["prefill_s"] = time.perf_counter() - t0
+        with trace.span("serve.prefill") as prefilling:
+            logits, pcache = prefill_fn(params, prompts)
+            jax.block_until_ready(pcache)
+        out["prefill_s"] = trace.seconds(prefilling)
 
         def splice_leaf(full, part):
             # cache leaves are stacked (L, B, ...); match on trailing dims
@@ -134,8 +146,9 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
                 return full.at[:, :, :part.shape[2]].set(part.astype(full.dtype))
             return part.astype(full.dtype)
 
-        cache = jax.tree.map(splice_leaf, cache, pcache)
-        tok = pick(logits, tok)
+        with trace.span("serve.splice"):
+            cache = jax.tree.map(splice_leaf, cache, pcache)
+            tok = pick(logits, tok)
         out.update(params=params, prompts=prompts, first_token=tok)
         print(f"prefill {args.prompt_len} tokens × {B} seqs in "
               f"{out['prefill_s']:.3f}s", flush=True)
@@ -145,38 +158,43 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
         paged = None
         session = None
         if args.spill:
-            spec = box.ClusterSpec(
-                num_donors=args.donors, donor_pages=1 << 14,
-                replication=args.replication,
-                num_clients=args.clients,
-                heap_pages=min(KV_HEAP_PAGES,
-                               (1 << 14) // args.clients // 2),
-                link={"latency_us": args.link_latency_us,
-                      "gbps": args.link_gbps},
-                faults=faults)
-            session = box.open(spec)
-            paged = session.kv_store(num_pages=256,
-                                     page_tokens=args.page_tokens,
-                                     kv_features=kv_features)
-            for b in range(B):
-                paged.add_sequence(b)
+            with trace.span("box.open"):
+                spec = box.ClusterSpec(
+                    num_donors=args.donors, donor_pages=1 << 14,
+                    replication=args.replication,
+                    num_clients=args.clients,
+                    heap_pages=min(KV_HEAP_PAGES,
+                                   (1 << 14) // args.clients // 2),
+                    link={"latency_us": args.link_latency_us,
+                          "gbps": args.link_gbps},
+                    faults=faults)
+                session = box.open(spec)
+                paged = session.kv_store(num_pages=256,
+                                         page_tokens=args.page_tokens,
+                                         kv_features=kv_features)
+                for b in range(B):
+                    paged.add_sequence(b)
 
         out_tokens = []
         first_logits = None
-        t0 = time.perf_counter()
-        for i in range(args.gen):
-            logits, cache, tok, cur = step_fn(params, cache, tok, cur)
-            if i == 0:
-                first_logits = logits
-            if not cfg.frontend:
-                out_tokens.append(np.asarray(tok))
-            if paged is not None:
-                kv_rows = rng.normal(size=(B, kv_features)).astype(np.float32)
-                for b in range(B):
-                    paged.append_tokens(b, kv_rows[b : b + 1])
-        jax.block_until_ready(cache)
-        dt = time.perf_counter() - t0
-        out["decode_tok_s"] = args.gen * B / dt
+        with trace.span("serve.decode") as decoding:
+            for i in range(args.gen):
+                with trace.span("serve.decode.step"):
+                    with trace.span("serve.decode.dispatch"):
+                        logits, cache, tok, cur = step_fn(params, cache, tok,
+                                                          cur)
+                    if i == 0:
+                        first_logits = logits
+                    if not cfg.frontend:
+                        with trace.span("serve.decode.token_read"):
+                            out_tokens.append(np.asarray(tok))
+                    if paged is not None:
+                        kv_rows = rng.normal(
+                            size=(B, kv_features)).astype(np.float32)
+                        for b in range(B):
+                            paged.append_tokens(b, kv_rows[b : b + 1])
+            jax.block_until_ready(cache)
+        out["decode_tok_s"] = args.gen * B / trace.seconds(decoding)
         if first_logits is not None:
             out["first_logits"] = np.asarray(first_logits, np.float32)
         print(f"decode {args.gen} steps × {B} seqs: "
@@ -186,16 +204,9 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
             arr = np.stack(out_tokens, axis=1)
             print("sample continuation token ids:", arr[0, :16].tolist())
         if paged is not None:
-            from repro.kernels.paged_attention.ops import descriptor_stats
-            Pmax = max(len(v) for v in paged.tables.values())
-            table = -np.ones((B, Pmax), np.int32)
-            for b in range(B):
-                table[b, : len(paged.tables[b])] = paged.tables[b]
-            print("page-run coalescing:", descriptor_stats(table, 4))
             # extra clients contend for the shared donors while the
             # serving client spills/fetches — the multi-client scenario
             bg_threads = []
-            bg_rates = {}
             if args.clients > 1:
                 import threading
 
@@ -205,10 +216,8 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
                     # thread-safe, and these threads run concurrently
                     r = np.random.default_rng(idx)
                     buf = r.integers(0, 255, 4096).astype(np.uint8)
-                    t0 = time.perf_counter()
                     for pid in range(n_pages):
                         pager.swap_out(pid, buf, wait=True)
-                    bg_rates[idx] = n_pages / (time.perf_counter() - t0)
 
                 bg_threads = [threading.Thread(target=bg_pager, args=(i,))
                               for i in range(1, args.clients)]
@@ -216,31 +225,37 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
                     t.start()
             # every sequence's pages round-trip through the donors and
             # must come back byte-for-byte
-            before = [paged.gather(b).copy() for b in range(B)]
-            for b in range(B):
-                paged.spill(b)
-            for b in range(B):
-                paged.fetch(b)
-            out["spill_exact"] = all(
-                np.array_equal(paged.gather(b).view(np.uint8),
-                               before[b].view(np.uint8)) for b in range(B))
+            with trace.span("serve.spill_check"):
+                before = [paged.gather(b).copy() for b in range(B)]
+                for b in range(B):
+                    paged.spill(b)
+                for b in range(B):
+                    paged.fetch(b)
+                out["spill_exact"] = all(
+                    np.array_equal(paged.gather(b).view(np.uint8),
+                                   before[b].view(np.uint8))
+                    for b in range(B))
             print(f"spill/fetch of {B} sequences byte-exact: "
                   f"{out['spill_exact']}", flush=True)
             for t in bg_threads:
                 t.join()
+            # the session opened in this job, so its counters are the
+            # job's own: the job's record takes them on its root span
             st = session.stats()
-            serving_nic = st["nic"][str(session.clients[0])]
-            merge = st["client"]["0"]["box"]["merge"]
-            print(f"spill/fetch: {serving_nic['rdma_ops']} RDMA ops, "
-                  f"merge drains {merge['drains']}")
-            if bg_rates:
-                print("background clients (pages/s under contention):",
-                      {session.clients[i]: f"{r:,.0f}"
-                       for i, r in sorted(bg_rates.items())})
-                print("donor-side per-client service:",
-                      st["fabric"]["service"])
-            session.close()
-        print("SERVING DONE")
+            root.add("rdma_ops",
+                     st["nic"][str(session.clients[0])]["rdma_ops"])
+            root.add("merge_drains", st["client"]["0"]["box"]["merge"]["drains"])
+            for key in KV_COUNTERS:
+                root.add(f"kv.{key}", st["kv"]["0"][key])
+            for donor, served in st["fabric"]["service"].items():
+                for client, v in served.items():
+                    root.add(f"donor{donor}.client{client}.ops", v["ops"])
+            with trace.span("box.close"):
+                session.close()
+    for name, row in trace.summary(root.job).items():
+        print(f"span {name}: " + " ".join(
+            f"{k}={v:.6g}" for k, v in row.items()))
+    print("SERVING DONE")
     return out
 
 

@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import trace
 from .._deprecation import warn_once
 from ..core.descriptors import PAGE_SIZE
 from ..core.rdmabox import RDMABox
@@ -128,9 +129,15 @@ class PagedKVCache:
         self._remote_next = remote_base_page                # bump allocator
         self._remote_free: List[int] = []
         self._lock = threading.Lock()   # guards alloc/tables/remote maps
-        # stats
+        # stats; spill and fetch bytes are what crosses the fabric, each
+        # page padded to whole RDMA pages
         self.gather_descriptors = 0
         self.gather_pages = 0
+        self.rows_appended = 0
+        self.pages_spilled = 0
+        self.bytes_spilled = 0
+        self.pages_fetched = 0
+        self.bytes_fetched = 0
 
     # ---- sequence lifecycle -------------------------------------------------
     def add_sequence(self, seq_id: int, num_tokens: int = 0) -> None:
@@ -142,16 +149,22 @@ class PagedKVCache:
 
     def append_tokens(self, seq_id: int, kv: np.ndarray) -> None:
         """kv: (T, kv_features) new tokens for the sequence."""
-        t = self.lengths[seq_id]
-        need = -(-(t + len(kv)) // self.page_tokens) - len(self.tables[seq_id])
-        if need > 0:
+        with trace.span("kv.append") as span:
+            t = self.lengths[seq_id]
+            need = (-(-(t + len(kv)) // self.page_tokens)
+                    - len(self.tables[seq_id]))
+            if need > 0:
+                with self._lock:
+                    self.tables[seq_id].extend(self.alloc.alloc(need))
+                span.add("pages", need)
+            for row in kv:
+                page = self.tables[seq_id][t // self.page_tokens]
+                self.pool[page, t % self.page_tokens] = row
+                t += 1
+            self.lengths[seq_id] = t
             with self._lock:
-                self.tables[seq_id].extend(self.alloc.alloc(need))
-        for row in kv:
-            page = self.tables[seq_id][t // self.page_tokens]
-            self.pool[page, t % self.page_tokens] = row
-            t += 1
-        self.lengths[seq_id] = t
+                self.rows_appended += len(kv)
+            span.add("rows", len(kv))
 
     def free_sequence(self, seq_id: int) -> None:
         self.alloc.free(self.tables.pop(seq_id))
@@ -178,50 +191,65 @@ class PagedKVCache:
     def spill_sequence(self, seq_id: int, donor: int) -> None:
         """Evict a sequence's pages to the remote pool (coalesced writes)."""
         assert self.box is not None, "no RDMA box attached"
-        pages = self.tables[seq_id]
-        # reserve ONE contiguous remote range per sequence: sequential spill
-        # writes stay adjacent ⇒ the merge queue coalesces them (and the
-        # fetch path reads back whole runs). Interleaving a shared bump
-        # pointer across threads would destroy exactly the adjacency the
-        # engine exploits.
-        with self._lock:
-            base_remote = self._remote_next
-            self._remote_next += len(pages) * self._rdma_pages
-        pairs = []
-        for pos, page in enumerate(pages):
-            remote = base_remote + pos * self._rdma_pages
-            data = np.ascontiguousarray(self.pool[page]).view(np.uint8).reshape(-1)
-            want = self._rdma_pages * PAGE_SIZE
-            if data.nbytes < want:                       # pad to page multiple
-                data = np.concatenate(
-                    [data, np.zeros(want - data.nbytes, np.uint8)])
-            pairs.append((remote, data))
-            self._spilled[(seq_id, pos)] = remote
-        # the sequence's whole range rides the batch API: one submit-lock
-        # acquisition, one future for the spill instead of one per page
-        self.box.write_pages(donor, pairs).wait()
-        with self._lock:
-            self.alloc.free(pages)
-        self.tables[seq_id] = [-1] * len(pages)   # -1 = remote
+        with trace.span("kv.spill") as span:
+            pages = self.tables[seq_id]
+            nbytes = len(pages) * self._rdma_pages * PAGE_SIZE
+            # reserve ONE contiguous remote range per sequence: sequential
+            # spill writes stay adjacent ⇒ the merge queue coalesces them
+            # (and the fetch path reads back whole runs). Interleaving a
+            # shared bump pointer across threads would destroy exactly the
+            # adjacency the engine exploits.
+            with self._lock:
+                base_remote = self._remote_next
+                self._remote_next += len(pages) * self._rdma_pages
+            pairs = []
+            for pos, page in enumerate(pages):
+                remote = base_remote + pos * self._rdma_pages
+                data = np.ascontiguousarray(
+                    self.pool[page]).view(np.uint8).reshape(-1)
+                want = self._rdma_pages * PAGE_SIZE
+                if data.nbytes < want:                   # pad to page multiple
+                    data = np.concatenate(
+                        [data, np.zeros(want - data.nbytes, np.uint8)])
+                pairs.append((remote, data))
+                self._spilled[(seq_id, pos)] = remote
+            # the sequence's whole range rides the batch API: one submit-lock
+            # acquisition, one future for the spill instead of one per page
+            self.box.write_pages(donor, pairs).wait()
+            with self._lock:
+                self.alloc.free(pages)
+                self.pages_spilled += len(pages)
+                self.bytes_spilled += nbytes
+            self.tables[seq_id] = [-1] * len(pages)   # -1 = remote
+            span.add("pages", len(pages))
+            span.add("bytes", nbytes)
 
     def fetch_sequence(self, seq_id: int, donor: int) -> None:
         """Bring a spilled sequence back (coalesced reads)."""
         assert self.box is not None
-        n = len(self.tables[seq_id])
-        with self._lock:
-            local = self.alloc.alloc(n)
-        pairs, bufs = [], []
-        for pos, page in enumerate(local):
+        with trace.span("kv.fetch") as span:
+            n = len(self.tables[seq_id])
+            nbytes = n * self._rdma_pages * PAGE_SIZE
             with self._lock:
-                remote = self._spilled.pop((seq_id, pos))
-                self._remote_free.append(remote)
-            buf = np.empty(self._rdma_pages * PAGE_SIZE, np.uint8)
-            pairs.append((remote, buf))
-            bufs.append((page, buf))
-        # one batched read for the sequence: donor-side copies land
-        # straight in the per-page buffers, one event for the whole fetch
-        self.box.read_pages(donor, pairs).wait()
-        for page, buf in bufs:
-            flat = buf[: self._page_bytes].view(self.dtype)
-            self.pool[page] = flat.reshape(self.page_tokens, self.kv_features)
-        self.tables[seq_id] = local
+                local = self.alloc.alloc(n)
+            pairs, bufs = [], []
+            for pos, page in enumerate(local):
+                with self._lock:
+                    remote = self._spilled.pop((seq_id, pos))
+                    self._remote_free.append(remote)
+                buf = np.empty(self._rdma_pages * PAGE_SIZE, np.uint8)
+                pairs.append((remote, buf))
+                bufs.append((page, buf))
+            # one batched read for the sequence: donor-side copies land
+            # straight in the per-page buffers, one event for the whole fetch
+            self.box.read_pages(donor, pairs).wait()
+            for page, buf in bufs:
+                flat = buf[: self._page_bytes].view(self.dtype)
+                self.pool[page] = flat.reshape(self.page_tokens,
+                                               self.kv_features)
+            self.tables[seq_id] = local
+            with self._lock:
+                self.pages_fetched += n
+                self.bytes_fetched += nbytes
+            span.add("pages", n)
+            span.add("bytes", nbytes)
