@@ -15,7 +15,7 @@ job's summary of its spans and counters is printed at its end.
 from __future__ import annotations
 
 import argparse
-from typing import Dict, Optional, Sequence
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,27 @@ KV_HEAP_PAGES = 1024
 # the KV tier's counters (its snapshot) that a job's record takes
 KV_COUNTERS = ("rows_appended", "pages_spilled", "bytes_spilled",
                "pages_fetched", "bytes_fetched")
+
+
+def programs(cfg) -> Tuple[Callable, Callable, Callable]:
+    """A serving job's jitted prefill and decode step, and its greedy
+    pick. The step takes the cache donated (argument 1): it writes each
+    layer's new row into it in place and hands it back."""
+    def pick(logits, tok):
+        # greedy next token; embedding-frontend archs feed their input on
+        if cfg.frontend:
+            return tok
+        return jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+
+    def serve_prefill(p, t):
+        return prefill(p, t, cfg)
+
+    def serve_step(p, c, t, i):
+        logits, c = decode_step(p, c, t, i, cfg)
+        return logits, c, pick(logits, t), i + 1
+
+    return (jax.jit(serve_prefill), jax.jit(serve_step, donate_argnums=(1,)),
+            pick)
 
 
 def run(argv: Optional[Sequence[str]] = None) -> Dict:
@@ -90,18 +111,7 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
     print(f"serve arch={cfg.name} on {device['platform']} {device['kind']} "
           f"x{device['count']}", flush=True)
 
-    def pick(logits, tok):
-        # greedy next token; embedding-frontend archs feed their input on
-        if cfg.frontend:
-            return tok
-        return jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
-
-    def serve_prefill(p, t):
-        return prefill(p, t, cfg)
-
-    def serve_step(p, c, t, i):
-        logits, c = decode_step(p, c, t, i, cfg)
-        return logits, c, pick(logits, t), i + 1
+    prefill_jit, step_jit, pick = programs(cfg)
 
     # the job's spans (repro.trace) time its windows: prefill_s,
     # compile_s and decode_tok_s are read from them
@@ -124,9 +134,13 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
 
         # compile outside the timed windows
         with trace.span("serve.compile") as compiling:
-            prefill_fn = jax.jit(serve_prefill).lower(params, prompts).compile()
-            step_fn = jax.jit(serve_step).lower(params, cache, tok,
-                                                cur).compile()
+            prefill_fn = prefill_jit.lower(params, prompts).compile()
+            step_fn = step_jit.lower(params, cache, tok, cur).compile()
+            # the input bytes the compiled step hands on to its output:
+            # the whole cache where it updates the cache in place
+            memory = step_fn.memory_analysis()
+            if memory is not None:
+                compiling.add("step_alias_bytes", memory.alias_size_in_bytes)
         out["compile_s"] = trace.seconds(compiling)
         print(f"compile prefill+decode: {out['compile_s']:.2f}s", flush=True)
 

@@ -243,25 +243,52 @@ def kv_cache_specs() -> Dict:
             "v": ("layers", "batch", "kv_seq", "kv_flat")}
 
 
-def attention_decode(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
-                     cur_index: jax.Array) -> Tuple[jax.Array, Dict]:
-    """x: (B, 1, M); cur_index: (B,) current write position (tokens so far).
+def write_row(stack: jax.Array, layer: jax.Array, slot: jax.Array,
+              row: jax.Array) -> jax.Array:
+    """Sequence b's new row ``row[b]`` written at ``(layer, b, slot[b])``
+    of a layer-stacked cache leaf ``(L, B, S, F)``: a scatter of B rows,
+    which XLA does in place, never a copy of a layer's slab."""
+    b_idx = jnp.arange(stack.shape[1])
+    return stack.at[layer, b_idx, slot].set(row.astype(stack.dtype))
+
+
+def block_diagonal(q: jax.Array) -> jax.Array:
+    """q (B, Kh, G, D) as (B, Kh·D, Kh·G), zero outside each KV head's
+    block: row kk·D + d of column kk·G + g holds q[:, kk, g, d]."""
+    B, Kh, G, D = q.shape
+    eye = jnp.eye(Kh, dtype=q.dtype)
+    return (q.transpose(0, 1, 3, 2)[:, :, :, None, :]
+            * eye[None, :, None, :, None]).reshape(B, Kh * D, Kh * G)
+
+
+def own_blocks(out: jax.Array, Kh: int, G: int, D: int) -> jax.Array:
+    """(B, Kh·G, Kh·D) -> (B, Kh, G, D): each head's own KV head's block."""
+    B = out.shape[0]
+    diag = jnp.diagonal(out.reshape(B, Kh, G, Kh, D), axis1=1, axis2=3)
+    return diag.transpose(0, 3, 1, 2)
+
+
+def attention_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
+                     cfg: ModelConfig, cur_index: jax.Array
+                     ) -> Tuple[jax.Array, Dict]:
+    """x: (B, 1, M); cache: the layer-stacked K and V ``(L, B, S, Kh·D)``;
+    layer: this layer's index; cur_index: (B,) current write position
+    (tokens so far). Writes the new K/V row into the stack and attends
+    over this layer's slab where it lies.
 
     Sliding-window archs store a ring buffer of ``window`` positions.
     """
     B = x.shape[0]
     H, Kh, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // Kh
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[2]
     q, k_new, v_new = qkv_proj(p, x, cfg, cur_index[:, None])
     slot = cur_index % S if cfg.window is not None else cur_index
-    b_idx = jnp.arange(B)
-    k_flat = cache["k"].at[b_idx, slot].set(
-        k_new[:, 0].reshape(B, Kh * D).astype(cache["k"].dtype))
-    v_flat = cache["v"].at[b_idx, slot].set(
-        v_new[:, 0].reshape(B, Kh * D).astype(cache["v"].dtype))
-    k = k_flat.reshape(B, S, Kh, D)
-    v = v_flat.reshape(B, S, Kh, D)
+    cache = {"k": write_row(cache["k"], layer, slot,
+                            k_new[:, 0].reshape(B, Kh * D)),
+             "v": write_row(cache["v"], layer, slot,
+                            v_new[:, 0].reshape(B, Kh * D))}
+    k, v = cache["k"][layer], cache["v"][layer]            # (B, S, Kh·D)
 
     kv_pos = jnp.arange(S)[None, :]                        # (1,S) slot index
     if cfg.window is not None:
@@ -271,11 +298,21 @@ def attention_decode(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
     else:
         valid = kv_pos <= cur_index[:, None]
 
-    qh = q.reshape(B, Kh, G, D).astype(jnp.float32)
-    s = jnp.einsum("bkgd,bskd->bkgs", qh, k.astype(jnp.float32)) * (D ** -0.5)
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", w, v.astype(jnp.float32))
+    # Both contractions read the slab as it lies, (S, Kh·D) with Kh·D
+    # minor, as one matmul per sequence over all heads: the scores
+    # against a block-diagonal q, w·V into every head's columns, of
+    # which each head keeps its own block. A (B, S, Kh, D) view of the
+    # slab would make XLA relayout it. Products keep the operands'
+    # precision: bf16 q and K into float32 sums, float32 w (HIGHEST)
+    # against V.
+    q_exp = block_diagonal(q.reshape(B, Kh, G, D))          # (B, Kh·D, H)
+    s = jnp.einsum("bsf,bfh->bsh", k, q_exp,
+                   preferred_element_type=jnp.float32) * (D ** -0.5)
+    s = jnp.where(valid[:, :, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=1)                            # (B, S, H)
+    out = own_blocks(jnp.einsum("bsh,bsf->bhf", w, v.astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST),
+                     Kh, G, D)
     out = out.reshape(B, 1, H * D).astype(x.dtype)
     y = jnp.einsum("bsh,hm->bsm", out, p["wo"])
-    return y, {"k": k_flat, "v": v_flat}
+    return y, cache

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import NEG_INF, flash_attention_jnp
+from .attention import NEG_INF, flash_attention_jnp, write_row
 from .layers import SpecTree, apply_rope, param, rms_norm
 
 
@@ -94,23 +94,25 @@ def mla_cache_specs() -> Dict:
             "k_pe": ("layers", "batch", "kv_seq", None)}
 
 
-def mla_decode(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
-               cur_index: jax.Array) -> Tuple[jax.Array, Dict]:
+def mla_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
+               cfg: ModelConfig, cur_index: jax.Array
+               ) -> Tuple[jax.Array, Dict]:
     """Absorbed-matmul MLA decode: attend in the latent space.
 
+    cache: the layer-stacked latent cache; the new row is written into
+    the stack at ``layer`` and the layer's slab is read where it lies.
     Scores: q_nope·W_kb (absorb) against cached c_kv; rope part separate.
     Memory roofline per token = R + dr bytes, not H·(dn+dv).
     """
     B = x.shape[0]
     H, R = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    S = cache["c_kv"].shape[1]
+    S = cache["c_kv"].shape[2]
     q_nope, q_pe, c_new, kpe_new = _mla_qkv(p, x, cfg, cur_index[:, None])
-    b_idx = jnp.arange(B)
-    c_kv = cache["c_kv"].at[b_idx, cur_index].set(
-        c_new[:, 0].astype(cache["c_kv"].dtype))
-    k_pe = cache["k_pe"].at[b_idx, cur_index].set(
-        kpe_new[:, 0].astype(cache["k_pe"].dtype))
+    cache = {"c_kv": write_row(cache["c_kv"], layer, cur_index, c_new[:, 0]),
+             "k_pe": write_row(cache["k_pe"], layer, cur_index,
+                               kpe_new[:, 0])}
+    c_kv, k_pe = cache["c_kv"][layer], cache["k_pe"][layer]
 
     wk_b = p["wk_b"].reshape(R, H, dn)
     wv_b = p["wv_b"].reshape(R, H, dv)
@@ -134,4 +136,4 @@ def mla_decode(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
     out = jnp.einsum("bhr,rhd->bhd", o_lat, wv_b.astype(jnp.float32))
     out = out.reshape(B, 1, H * dv).astype(x.dtype)
     y = jnp.einsum("bsh,hm->bsm", out, p["wo"])
-    return y, {"c_kv": c_kv, "k_pe": k_pe}
+    return y, cache

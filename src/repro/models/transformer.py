@@ -214,22 +214,29 @@ def cache_specs(cfg: ModelConfig) -> Dict:
     return specs
 
 
-def block_decode(p: Dict, x: jax.Array, layer_cache: Dict, cfg: ModelConfig,
-                 cur_index: jax.Array) -> Tuple[jax.Array, Dict]:
-    new_cache: Dict = {}
+def block_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
+                 cfg: ModelConfig, cur_index: jax.Array
+                 ) -> Tuple[jax.Array, Dict]:
+    """One layer's decode against the layer-stacked cache. Attention and
+    MLA write their new row into the stack at ``layer``; the SSM state
+    is the whole state, so the layer's is written back whole."""
+    cache = dict(cache)
     h = rms_norm(x, p["norm_mixer"], cfg.norm_eps)
     mixed = jnp.zeros_like(x)
     if cfg.uses_attention:
         if cfg.attention == "mla":
-            a, new_cache["mla"] = mla_mod.mla_decode(
-                p["mla"], h, layer_cache["mla"], cfg, cur_index)
+            a, cache["mla"] = mla_mod.mla_decode(
+                p["mla"], h, cache["mla"], layer, cfg, cur_index)
         else:
-            a, new_cache["attn"] = attn_mod.attention_decode(
-                p["attn"], h, layer_cache["attn"], cfg, cur_index)
+            a, cache["attn"] = attn_mod.attention_decode(
+                p["attn"], h, cache["attn"], layer, cfg, cur_index)
         mixed = mixed + a
     if cfg.uses_ssm:
-        s, new_cache["ssm"] = ssm_mod.ssm_decode(
-            p["ssm"], h, layer_cache["ssm"], cfg, cur_index)
+        state = jax.tree.map(lambda c: c[layer], cache["ssm"])
+        s, state = ssm_mod.ssm_decode(p["ssm"], h, state, cfg, cur_index)
+        cache["ssm"] = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                c, n.astype(c.dtype), layer, 0), cache["ssm"], state)
         mixed = 0.5 * (mixed + s) if cfg.mixer == "hybrid" else mixed + s
     x = x + mixed
     h = rms_norm(x, p["norm_ffn"], cfg.norm_eps)
@@ -239,27 +246,35 @@ def block_decode(p: Dict, x: jax.Array, layer_cache: Dict, cfg: ModelConfig,
         y = apply_mlp(p["mlp"], h)
     else:
         y = jnp.zeros_like(h)
-    return x + y, new_cache
+    return x + y, cache
 
 
 def decode_step(params: Dict, cache: Dict, token_or_embed: jax.Array,
                 cur_index: jax.Array, cfg: ModelConfig
                 ) -> Tuple[jax.Array, Dict]:
-    """One decode step. token (B,) int32 or embed (B, M). cur_index (B,)."""
+    """One decode step. token (B,) int32 or embed (B, M). cur_index (B,).
+
+    The stacked cache is the layer loop's carry, not its ``xs``/``ys``:
+    each layer writes its new rows into it in place and reads its slab
+    where it lies, so a step moves the rows it writes and the slabs
+    attention reads, never a copy of the cache (donate it to the jitted
+    step, or the first write copies it once)."""
     if token_or_embed.ndim == 1:
         x = params["embed"][token_or_embed][:, None, :]      # (B,1,M)
     else:
         x = token_or_embed[:, None, :].astype(params["embed"].dtype)
 
-    def scan_fn(x, inp):
-        layer_params, layer_cache = inp
-        x, new_c = block_decode(layer_params, x, layer_cache, cfg, cur_index)
-        return x, new_c
+    def scan_fn(carry, inp):
+        x, cache = carry
+        layer, layer_params = inp
+        return block_decode(layer_params, x, cache, layer, cfg,
+                            cur_index), None
 
-    x, new_cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
+    (x, cache), _ = jax.lax.scan(
+        scan_fn, (x, cache), (jnp.arange(cfg.num_layers), params["blocks"]))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:
         unembed = params["embed"].T
     logits = jnp.einsum("bsm,mv->bsv", x, unembed)[:, 0]
-    return logits, new_cache
+    return logits, cache

@@ -88,6 +88,84 @@ def test_decode_matches_forward(arch):
     assert np.abs(a - b).max() / denom < 0.05, f"{arch}: decode diverges"
 
 
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "deepseek-v2-lite-16b",
+                                  "hymba-1.5b"])
+def test_decode_at_staggered_positions_matches_each_sequence_alone(arch):
+    """Two sequences decoded together at different positions give the
+    logits each gives decoded alone: each writes its new row at its own
+    slot of the stacked cache (GQA, MLA's latent cache, and the ring
+    buffer of a sliding window beside SSM state, with a window small
+    enough that both rings wrap, at different slots)."""
+    from repro.configs import replace
+    cfg = get_reduced(arch)
+    if cfg.num_experts:       # continuous gating: see the test above
+        cfg = replace(cfg, top_k=cfg.num_experts, capacity_factor=2.0)
+    if cfg.window is not None:
+        cfg = replace(cfg, window=8)
+    params, _ = init_stack(jax.random.PRNGKey(1), cfg)
+    LEAD, T = 5, 14                      # sequence 0 runs LEAD steps ahead
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, LEAD + T), 0,
+                                cfg.vocab_size)
+    step = jax.jit(lambda p, c, t, i: decode_step(p, c, t, i, cfg))
+
+    def alone(b, n):
+        cache, outs, lead = init_cache(cfg, 1, max_len=LEAD + T), [], None
+        for t in range(n):
+            if t == LEAD:
+                lead = cache
+            logits, cache = step(params, cache, tokens[b:b + 1, t],
+                                 jnp.full((1,), t, jnp.int32))
+            outs.append(np.asarray(logits[0], np.float32))
+        return outs, lead
+
+    first, lead = alone(0, LEAD + T)
+    second, _ = alone(1, T)
+    cache = jax.tree.map(lambda a, b: jnp.concatenate([a, b], 1), lead,
+                         init_cache(cfg, 1, max_len=LEAD + T))
+    for t in range(T):
+        logits, cache = step(params, cache, tokens[:, [LEAD + t, t]].diagonal(),
+                             jnp.array([LEAD + t, t], jnp.int32))
+        got = np.asarray(logits, np.float32)
+        for b, want in ((0, first[LEAD + t]), (1, second[t])):
+            err = np.abs(got[b] - want).max() / max(np.abs(want).max(), 1.0)
+            assert err < 0.02, f"{arch}: sequence {b} at step {t}: {err}"
+
+
+def test_serving_step_takes_the_cache_donated_and_writes_rows():
+    """The serving step, lowered and compiled as ``serve.run`` does,
+    aliases every cache leaf from its input to its output, and writes no
+    whole layer's slab: no dynamic_update_slice of a cache-shaped array
+    whose update spans the sequence axis."""
+    import re
+
+    from repro.launch import serve
+    cfg = get_reduced("qwen1.5-0.5b")
+    params, _ = init_stack(KEY, cfg)
+    B, S = 2, 24
+    cache = init_cache(cfg, B, max_len=S)
+    lowered = serve.programs(cfg)[1].lower(
+        params, cache, jnp.zeros((B,), jnp.int32), jnp.full((B,), 16, jnp.int32))
+    leaves = jax.tree.leaves(cache)
+
+    def dims(t):
+        return tuple(int(d) for d in t.split("x")[:-1])
+
+    for line in lowered.as_text().splitlines():
+        m = re.search(r"stablehlo\.dynamic_update_slice .*: \(tensor<(\S+?)>, "
+                      r"tensor<(\S+?)>", line)
+        if m:
+            into, update = dims(m.group(1)), dims(m.group(2))
+            assert not (any(into == a.shape for a in leaves)
+                        and update[2] == S), line.strip()
+    compiled = lowered.compile()
+    first = len(jax.tree.leaves(params))       # the cache's leaves follow
+    aliased = {int(n) for n in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", compiled.as_text())}
+    assert set(range(first, first + len(leaves))) <= aliased
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.nbytes for a in leaves)
+
+
 def test_prefill_then_decode_continues():
     cfg = get_reduced("qwen1.5-0.5b")
     params, _ = init_stack(KEY, cfg)

@@ -191,6 +191,19 @@ def test_serving_job_counts_the_kv_tier_and_the_engine(served):
     assert root["rdma_ops"] > 0 and root["merge_drains"] > 0
 
 
+def test_serving_job_counts_the_cache_bytes_its_step_aliases(served):
+    """``serve.compile`` carries ``step_alias_bytes``: the compiled
+    decode step hands the whole donated cache on to its output."""
+    from repro.configs import get_reduced
+    from repro.models import init_cache
+
+    _, job = served
+    cache = jax.eval_shape(lambda: init_cache(
+        get_reduced("qwen1.5-0.5b"), B, max_len=16 + GEN))
+    nbytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert trace.summary(job)["serve.compile"]["step_alias_bytes"] == nbytes > 0
+
+
 def test_no_compile_is_counted_inside_the_decode_loop(served):
     _, job = served
     spans = job.spans
