@@ -8,6 +8,19 @@ from typing import Dict, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling, as a published ``rope_scaling`` of type yarn
+    states it (DeepSeek-V2)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | audio | vlm
@@ -23,6 +36,7 @@ class ModelConfig:
     qkv_bias: bool = False
     window: Optional[int] = None     # sliding-window size (None = full causal)
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[Yarn] = None   # MLA's rope part only
     # --- MLA (DeepSeek-V2) ---------------------------------------------------
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
@@ -35,8 +49,14 @@ class ModelConfig:
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    first_k_dense: int = 0           # leading layers with a dense FFN of d_ff
+    norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
+    routed_scaling: float = 1.0      # routed experts' gates times this
     router_aux_weight: float = 0.001
+    # expert parallelism: each MoE layer's routed experts are divided over
+    # ``expert_parallel`` chips; this one, ``expert_rank``, holds its share
+    expert_parallel: int = 1
+    expert_rank: int = 0
     # --- SSM (Mamba-2 / SSD) ---------------------------------------------------
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -76,6 +96,20 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def experts_held(self) -> int:
+        """Routed experts of each MoE layer whose weights this chip holds."""
+        return self.num_experts // self.expert_parallel
+
+    @property
+    def expert_offset(self) -> int:
+        """Global id of the first expert this chip holds."""
+        return self.expert_rank * self.experts_held
+
+    @property
+    def moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense if self.uses_moe else 0
+
+    @property
     def d_inner(self) -> int:
         """SSM inner width."""
         return self.ssm_heads * self.ssm_head_dim
@@ -93,6 +127,7 @@ class ModelConfig:
         if not c.tie_embeddings:
             n += c.vocab_size * c.d_model     # unembed
         per_layer = 2 * c.d_model             # 2 rmsnorm
+        dense_layers = c.num_layers - c.moe_layers
         if c.uses_attention:
             if c.attention == "mla":
                 q_dim = c.num_heads * (c.qk_nope_dim + c.qk_rope_dim)
@@ -112,13 +147,14 @@ class ModelConfig:
             per_layer += c.d_model * c.ssm_heads                        # dt proj
             per_layer += d_in * c.d_model                               # out proj
             per_layer += 2 * c.ssm_heads                                # A_log, D
-        if c.d_ff:
-            per_layer += 3 * c.d_model * c.d_ff                         # swiglu
+        n += c.num_layers * per_layer
+        n += dense_layers * 3 * c.d_model * c.d_ff                      # swiglu
         if c.uses_moe:
-            per_layer += c.d_model * c.num_experts                      # router
-            per_layer += c.num_experts * 3 * c.d_model * c.moe_d_ff
-            per_layer += c.num_shared_experts * 3 * c.d_model * c.moe_d_ff
-        return n + c.num_layers * per_layer
+            moe = c.d_model * c.num_experts                             # router
+            moe += c.num_experts * 3 * c.d_model * c.moe_d_ff
+            moe += c.num_shared_experts * 3 * c.d_model * c.moe_d_ff
+            n += c.moe_layers * moe
+        return n
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top_k + shared only)."""
@@ -126,8 +162,8 @@ class ModelConfig:
             return self.param_count()
         c = self
         full = self.param_count()
-        routed_all = c.num_layers * c.num_experts * 3 * c.d_model * c.moe_d_ff
-        routed_active = c.num_layers * c.top_k * 3 * c.d_model * c.moe_d_ff
+        routed_all = c.moe_layers * c.num_experts * 3 * c.d_model * c.moe_d_ff
+        routed_active = c.moe_layers * c.top_k * 3 * c.d_model * c.moe_d_ff
         return full - routed_all + routed_active
 
 

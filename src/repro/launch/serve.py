@@ -22,23 +22,38 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import box, trace
-from repro.configs import get_config, get_reduced
+from repro.configs import get_config, get_reduced, replace
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import device_info, make_local_mesh
 from repro.models import decode_step, init_cache, init_stack, prefill
 
 # pages reserved per client for the KV spill arena (the heap slice of
-# each donor region); the rest of the slice backs background paging
+# each donor region), at least; the rest of the slice backs background
+# paging
 KV_HEAP_PAGES = 1024
+# the most prompt tokens one prefill pass takes: a larger batch is
+# prefilled in groups of whole sequences, which bounds its transients
+PREFILL_TOKENS = 8192
 # the KV tier's counters (its snapshot) that a job's record takes
 KV_COUNTERS = ("rows_appended", "pages_spilled", "bytes_spilled",
                "pages_fetched", "bytes_fetched")
 
 
+def prefill_groups(batch: int, prompt_len: int) -> int:
+    """How many groups of whole sequences a batch is prefilled in: the
+    fewest that keep a group within ``PREFILL_TOKENS``."""
+    return next((g for g in range(1, batch + 1)
+                 if batch % g == 0 and batch // g * prompt_len
+                 <= PREFILL_TOKENS), batch)
+
+
 def programs(cfg) -> Tuple[Callable, Callable, Callable]:
     """A serving job's jitted prefill and decode step, and its greedy
-    pick. The step takes the cache donated (argument 1): it writes each
-    layer's new row into it in place and hands it back."""
+    pick. The prefill runs a batch of more than ``PREFILL_TOKENS`` prompt
+    tokens in groups of sequences (``prefill_groups``), one after the
+    other, each writing its rows of the logits and the cache. The step
+    takes the cache donated (argument 1): it writes each layer's new row
+    into it in place and hands it back."""
     def pick(logits, tok):
         # greedy next token; embedding-frontend archs feed their input on
         if cfg.frontend:
@@ -46,7 +61,28 @@ def programs(cfg) -> Tuple[Callable, Callable, Callable]:
         return jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
 
     def serve_prefill(p, t):
-        return prefill(p, t, cfg)
+        groups = prefill_groups(*t.shape[:2])
+        if groups == 1:
+            return prefill(p, t, cfg)
+        n = t.shape[0] // groups
+        part = jax.eval_shape(lambda x: prefill(p, x, cfg), t[:n])
+
+        def whole(s, axis):        # the batch's buffer of a group's leaf
+            return jnp.zeros(s.shape[:axis] + t.shape[:1]
+                             + s.shape[axis + 1:], s.dtype)
+
+        def group(i, out):
+            logits, cache = prefill(
+                p, jax.lax.dynamic_slice_in_dim(t, i * n, n), cfg)
+            put = jax.lax.dynamic_update_slice_in_dim
+            # the logits are (B, V), cache leaves stacked (L, B, ...)
+            return (put(out[0], logits, i * n, 0),
+                    jax.tree.map(lambda a, c: put(a, c, i * n, 1),
+                                 out[1], cache))
+
+        return jax.lax.fori_loop(
+            0, groups, group,
+            (whole(part[0], 0), jax.tree.map(lambda s: whole(s, 1), part[1])))
 
     def serve_step(p, c, t, i):
         logits, c = decode_step(p, c, t, i, cfg)
@@ -67,6 +103,10 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--page-tokens", type=int, default=16)
+    ap.add_argument("--expert-parallel", type=int, default=1, metavar="N",
+                    help="chips that divide each MoE layer's routed experts; "
+                         "this one holds its 1/N share (rank 0) and adds "
+                         "only those experts' outputs")
     ap.add_argument("--spill", action="store_true",
                     help="spill finished sequences' KV to remote memory")
     # fabric topology + degraded-mode scenario surface
@@ -104,6 +144,11 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
     use_compile_cache()
     device = device_info()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    ep = args.expert_parallel
+    if ep < 1 or (ep > 1 and (not cfg.uses_moe or cfg.num_experts % ep)):
+        ap.error(f"--expert-parallel {ep} does not divide the "
+                 f"{cfg.num_experts} routed experts of {cfg.name}")
+    cfg = replace(cfg, expert_parallel=ep)
     mesh = make_local_mesh(1, 1)
     B, S = args.batch, args.prompt_len + args.gen
     rng = np.random.default_rng(0)
@@ -141,6 +186,12 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
             memory = step_fn.memory_analysis()
             if memory is not None:
                 compiling.add("step_alias_bytes", memory.alias_size_in_bytes)
+            if cfg.uses_moe:
+                compiling.add("moe.experts_held", cfg.experts_held)
+                compiling.add("moe.experts_routed", cfg.num_experts)
+            if "mla" in cache:
+                compiling.add("mla.cache_bytes", sum(
+                    a.nbytes for a in jax.tree.leaves(cache["mla"])))
         out["compile_s"] = trace.seconds(compiling)
         print(f"compile prefill+decode: {out['compile_s']:.2f}s", flush=True)
 
@@ -161,6 +212,8 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
             return part.astype(full.dtype)
 
         with trace.span("serve.splice"):
+            if "moe" in pcache:          # read after the decode window
+                held_prefill = jnp.sum(pcache["moe"]["assign"])
             cache = jax.tree.map(splice_leaf, cache, pcache)
             tok = pick(logits, tok)
         out.update(params=params, prompts=prompts, first_token=tok)
@@ -172,18 +225,21 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
         paged = None
         session = None
         if args.spill:
+            # every sequence's decoded rows, in whole pages
+            kv_pages = B * -(-args.gen // args.page_tokens)
             with trace.span("box.open"):
                 spec = box.ClusterSpec(
                     num_donors=args.donors, donor_pages=1 << 14,
                     replication=args.replication,
                     num_clients=args.clients,
-                    heap_pages=min(KV_HEAP_PAGES,
+                    heap_pages=min(max(KV_HEAP_PAGES,
+                                       kv_pages * args.replication),
                                    (1 << 14) // args.clients // 2),
                     link={"latency_us": args.link_latency_us,
                           "gbps": args.link_gbps},
                     faults=faults)
                 session = box.open(spec)
-                paged = session.kv_store(num_pages=256,
+                paged = session.kv_store(num_pages=kv_pages,
                                          page_tokens=args.page_tokens,
                                          kv_features=kv_features)
                 for b in range(B):
@@ -209,6 +265,14 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
                             paged.append_tokens(b, kv_rows[b : b + 1])
             jax.block_until_ready(cache)
         out["decode_tok_s"] = args.gen * B / trace.seconds(decoding)
+        if "moe" in cache:
+            # the routing the device counted, read once the window is over
+            counts = jax.device_get(cache["moe"])
+            prefilled = int(held_prefill)
+            root.add("moe.assign_held.prefill", prefilled)
+            root.add("moe.assign_held.decode",
+                     int(counts["assign"].sum()) - prefilled)
+            root.add("moe.expert_load_max", int(counts["load_max"].max()))
         if first_logits is not None:
             out["first_logits"] = np.asarray(first_logits, np.float32)
         print(f"decode {args.gen} steps × {B} seqs: "

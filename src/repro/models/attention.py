@@ -67,18 +67,19 @@ def flash_attention_jnp(
     *, causal: bool = True, window: Optional[int] = None,
     q_block: int = 512, kv_block: int = 512,
     q_offset: int = 0, bf16_compute: bool = False,
-    swa_sliced_kv: bool = False,
+    swa_sliced_kv: bool = False, scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax attention.
 
     q: (B, Sq, H, D); k, v: (B, Skv, Kh, D) with H a multiple of Kh.
     Never materializes more than (q_block × kv_block) scores per (B, head).
     ``q_offset`` positions q tokens at ``q_offset + i`` against kv.
+    Scores are scaled by ``scale`` (default D^-0.5).
     """
     B, Sq, H, D = q.shape
     _, Skv, Kh, _ = k.shape
     G = H // Kh
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     op_dtype = q.dtype if bf16_compute else jnp.float32
 
     if window is not None and swa_sliced_kv and Skv > window + q_block:

@@ -1,9 +1,10 @@
 """Multi-head Latent Attention (DeepSeek-V2): compressed KV cache.
 
 The KV cache stores only the low-rank latent ``c_kv`` (kv_lora_rank) plus
-the decoupled RoPE key ``k_pe`` — 576 floats/token for V2-Lite instead of
-16 heads × 2 × 128. Small pages ⇒ more pages per byte budget ⇒ the paper's
-run-coalescing matters *more* here (DESIGN.md §Arch-applicability).
+the decoupled RoPE key ``k_pe`` — 576 values/token for V2-Lite instead of
+16 heads × 2 × 128. The rope part follows DeepSeek: its pairs are
+interleaved (x[2i], x[2i+1]), its frequencies and softmax scale follow
+the config's YaRN ``rope_scaling``.
 """
 
 from __future__ import annotations
@@ -15,7 +16,26 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .attention import NEG_INF, flash_attention_jnp, write_row
-from .layers import SpecTree, apply_rope, param, rms_norm
+from .layers import SpecTree, apply_rope, param, rms_norm, yarn_mscale
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times mscale(factor, mscale_all_dim)²
+    under YaRN."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    yarn = cfg.rope_scaling
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """DeepSeek's interleaved rope: the pairs (x[2i], x[2i+1]) are moved
+    to (i, i + D/2) and turned in rotate-half form; q and k move alike,
+    so their products are the interleaved form's."""
+    d = x.shape[-1]
+    x = x.reshape(*x.shape[:-1], d // 2, 2).swapaxes(-1, -2).reshape(x.shape)
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling)
 
 
 def init_mla(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
@@ -42,11 +62,11 @@ def _mla_qkv(p: Dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     R, dr, dn, dv = cfg.kv_lora_rank, cfg.qk_rope_dim, cfg.qk_nope_dim, cfg.v_head_dim
     q = jnp.einsum("bsm,mh->bsh", x, p["wq"]).reshape(B, S, H, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = _rope(q_pe, positions, cfg)
     kv = jnp.einsum("bsm,mr->bsr", x, p["wkv_a"])
     c_kv, k_pe = kv[..., :R], kv[..., R:]
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)       # (B,S,dr)
+    k_pe = _rope(k_pe, positions, cfg)                       # (B,S,dr)
     return q_nope, q_pe, c_kv, k_pe
 
 
@@ -70,7 +90,8 @@ def mla_train(p: Dict, x: jax.Array, cfg: ModelConfig,
     # pad v head_dim up to qk dim for the shared flash path, slice after
     pad = q.shape[-1] - dv
     v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-    out = flash_attention_jnp(q, k, v_p, causal=True)[..., :dv]
+    out = flash_attention_jnp(q, k, v_p, causal=True,
+                              scale=softmax_scale(cfg))[..., :dv]
     out = out.reshape(B, S, H * dv)
     y = jnp.einsum("bsh,hm->bsm", out, p["wo"])
     if not return_kv:
@@ -128,7 +149,7 @@ def mla_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
     s = jnp.einsum("bhr,bsr->bhs", q_lat, c_kv.astype(jnp.float32))
     s += jnp.einsum("bhd,bsd->bhs", q_pe[:, 0].astype(jnp.float32),
                     k_pe.astype(jnp.float32))
-    s *= (dn + dr) ** -0.5
+    s *= softmax_scale(cfg)
     valid = jnp.arange(S)[None, :] <= cur_index[:, None]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)

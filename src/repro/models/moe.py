@@ -1,10 +1,16 @@
-"""Mixture-of-Experts with sort-based grouped dispatch (dropless-ish).
+"""Mixture-of-Experts with sort-based dropless dispatch.
 
-Tokens are sorted by assigned expert and packed into per-expert capacity
-buffers, so the expert matmuls are dense (E, C, M) × (E, M, F) einsums whose
-FLOPs scale with *active* params × capacity_factor — not with E/top_k as a
-mask-everything implementation would. Tokens overflowing an expert's
-capacity are dropped (standard capacity-factor semantics).
+The router scores every expert of the layer (softmax, then greedy
+top-k; the gates are renormalized only where ``cfg.norm_topk_prob``
+says so, then scaled by ``cfg.routed_scaling``). A layer holds the
+weights of ``cfg.experts_held`` consecutive experts from
+``cfg.expert_offset`` on: all of them on one chip, or this chip's share
+where ``cfg.expert_parallel`` chips divide the layer. The assignments to
+held experts are sorted by expert into one buffer of
+T · min(top_k, held) rows, which holds every one of them, and each
+expert's rows go through its SwiGLU as one group of a ragged matmul: no
+token is dropped, and the FLOPs are those of the assignments. What
+experts held elsewhere would add is left to their chips.
 
 Shared experts are fused into one dense swiglu of width shared·moe_d_ff.
 """
@@ -20,16 +26,34 @@ from ..configs.base import ModelConfig
 from .layers import SpecTree, param, swiglu
 
 
+def _experts(key: jax.Array, first: int, n: int, shape: Tuple[int, int],
+             axes, specs: SpecTree, name: str) -> jax.Array:
+    """Experts ``first .. first + n - 1`` of one weight, stacked: expert e
+    is drawn from ``fold_in(key, e)`` at fan-in scale, so its weights do
+    not depend on how many experts are held."""
+    specs.record(name, axes)
+
+    def one(e):
+        w = jax.random.normal(jax.random.fold_in(key, e), shape, jnp.float32)
+        return (w * shape[0] ** -0.5).astype(jnp.bfloat16)
+
+    return jax.vmap(one)(jnp.arange(first, first + n))
+
+
 def init_moe(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
     sub = specs.sub("moe")
     ks = jax.random.split(key, 8)
     M, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    first, n = cfg.expert_offset, cfg.experts_held
     p = {
         "router": param(ks[0], (M, E), ("embed", None), sub, "router",
                         scale=M ** -0.5, dtype=jnp.float32),
-        "wi": param(ks[1], (E, M, F), ("experts", "embed", "moe_ff"), sub, "wi"),
-        "wg": param(ks[2], (E, M, F), ("experts", "embed", "moe_ff"), sub, "wg"),
-        "wo": param(ks[3], (E, F, M), ("experts", "moe_ff", "embed"), sub, "wo"),
+        "wi": _experts(ks[1], first, n, (M, F),
+                       ("experts", "embed", "moe_ff"), sub, "wi"),
+        "wg": _experts(ks[2], first, n, (M, F),
+                       ("experts", "embed", "moe_ff"), sub, "wg"),
+        "wo": _experts(ks[3], first, n, (F, M),
+                       ("experts", "moe_ff", "embed"), sub, "wo"),
     }
     if cfg.num_shared_experts:
         Fs = cfg.num_shared_experts * F
@@ -39,17 +63,13 @@ def init_moe(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
     return p
 
 
-def _capacity(tokens: int, cfg: ModelConfig) -> int:
-    cap = int(tokens * cfg.top_k / cfg.num_experts * cfg.capacity_factor)
-    return max(8, -(-cap // 8) * 8)  # round up to 8
-
-
 def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig,
                    expert_offset, num_local_experts: int,
-                   wi, wg, wo) -> Tuple[jax.Array, jax.Array]:
-    """Sort-based capacity dispatch of ``xt`` (T, M) to the ``E_loc``
-    experts whose weights are in wi/wg/wo, with global expert ids offset by
-    ``expert_offset`` (EP slice). Returns (y (T,M) f32 partial, aux)."""
+                   wi, wg, wo) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless dispatch of ``xt`` (T, M) to the ``E_loc`` experts whose
+    weights are in wi/wg/wo, global ids ``expert_offset ..`` (EP slice).
+    Returns (y (T, M) f32 partial, aux, held (T, E_loc) int32: how many
+    of each token's top-k went to each held expert, 0 or 1)."""
     T, M = xt.shape
     E, K = cfg.num_experts, cfg.top_k
     E_loc = num_local_experts
@@ -57,68 +77,63 @@ def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig,
     logits = jnp.einsum("tm,me->te", xt.astype(jnp.float32), p["router"])
     probs = jax.nn.softmax(logits, axis=-1)                       # (T, E)
     gate, expert_idx = jax.lax.top_k(probs, K)                    # (T, K)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    gate = gate * cfg.routed_scaling
 
     # ---- load-balancing aux loss (Switch-style, over global experts) ----
     me = probs.mean(axis=0)                                       # (E,)
     ce = jnp.zeros(E).at[expert_idx.reshape(-1)].add(1.0) / (T * K)
     aux = cfg.router_aux_weight * E * jnp.sum(me * ce)
 
-    # ---- sort-based dispatch over the local expert slice ----
-    C = _capacity(T, cfg)
+    # ---- sort the held assignments by expert: one row each ----
     local_e = expert_idx.reshape(-1) - expert_offset              # (T*K,)
     in_slice = (local_e >= 0) & (local_e < E_loc)
     flat_e = jnp.where(in_slice, local_e, E_loc)                  # E_loc = out
-    order = jnp.argsort(flat_e)                                   # stable
-    sorted_e = flat_e[order]
-    counts = jnp.zeros(E_loc + 1, jnp.int32).at[flat_e].add(1)
-    starts = jnp.cumsum(counts) - counts                          # (E_loc+1,)
-    rank = jnp.arange(T * K) - starts[jnp.minimum(sorted_e, E_loc)]
-    valid = (rank < C) & (sorted_e < E_loc)
-    slot = jnp.where(valid, sorted_e * C + rank, E_loc * C)       # trash row
-    token_of = order // K                                         # (T*K,)
+    rows = T * min(K, E_loc)              # a token sends ≤ min(K, E_loc) here
+    order = jnp.argsort(flat_e, stable=True)[:rows]
+    held = in_slice[order]
+    sizes = jnp.zeros(E_loc + 1, jnp.int32).at[flat_e].add(1)[:E_loc]
+    token_of = order // K                                         # (rows,)
 
-    src = jnp.zeros(E_loc * C + 1, jnp.int32).at[slot].set(token_of)
-    occupied = jnp.zeros(E_loc * C + 1, jnp.bool_).at[slot].set(valid)
-    src, occupied = src[:-1], occupied[:-1]
+    grouped = xt[token_of]                                        # (rows, M)
+    h = jax.lax.ragged_dot(grouped, wi, sizes)
+    g = jax.lax.ragged_dot(grouped, wg, sizes)
+    yg = jax.lax.ragged_dot(h * jax.nn.silu(g), wo, sizes)        # (rows, M)
 
-    grouped = xt[src] * occupied[:, None].astype(xt.dtype)        # (E_loc*C, M)
-    grouped = grouped.reshape(E_loc, C, M)
-    h = jnp.einsum("ecm,emf->ecf", grouped, wi)
-    g = jnp.einsum("ecm,emf->ecf", grouped, wg)
-    yg = jnp.einsum("ecf,efm->ecm", h * jax.nn.silu(g), wo)
-    yg = yg.reshape(E_loc * C, M)
-
-    gate_flat = gate.reshape(-1)[order]                            # (T*K,)
-    w_slot = jnp.where(valid, gate_flat, 0.0)
-    w_of_slot = jnp.zeros(E_loc * C + 1, jnp.float32).at[slot].set(w_slot)[:-1]
-    y = jnp.zeros((T, M), jnp.float32).at[src].add(
-        yg.astype(jnp.float32) * w_of_slot[:, None] * occupied[:, None])
-    return y, aux
+    w = jnp.where(held, gate.reshape(-1)[order], 0.0)
+    y = jnp.zeros((T, M), jnp.float32).at[token_of].add(
+        yg.astype(jnp.float32) * w[:, None])
+    counts = jax.nn.one_hot(jnp.where(in_slice, local_e, -1), E_loc,
+                            dtype=jnp.int32).reshape(T, K, E_loc).sum(1)
+    return y, aux, counts
 
 
 def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, M) → (out, aux_loss)."""
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, M) → (out, aux_loss, held (B, S, experts held): each
+    token's assignments to each held expert)."""
     if cfg.moe_shard_map:
-        y, aux = _moe_shard_map(p, x, cfg)
-        if y is not None:
-            return y, aux
+        out = _moe_shard_map(p, x, cfg)
+        if out is not None:
+            return out
     B, S, M = x.shape
     xt = x.reshape(B * S, M)
-    E = cfg.num_experts
-    y, aux = _dispatch_core(xt, p, cfg, 0, E, p["wi"], p["wg"], p["wo"])
+    E_loc = cfg.experts_held
+    y, aux, held = _dispatch_core(xt, p, cfg, cfg.expert_offset, E_loc,
+                                  p["wi"], p["wg"], p["wo"])
     if cfg.num_shared_experts:
         y = y + swiglu(xt, p["shared_wi"], p["shared_wg"],
                        p["shared_wo"]).astype(jnp.float32)
-    return y.reshape(B, S, M).astype(x.dtype), aux
+    return (y.reshape(B, S, M).astype(x.dtype), aux,
+            held.reshape(B, S, E_loc))
 
 
 def _moe_shard_map(p: Dict, x: jax.Array, cfg: ModelConfig):
     """Shard-local MoE dispatch (§Perf, beyond-paper optimization).
 
     The global-dispatch path gathers the whole token batch to build the
-    (E, C, M) capacity buffers — XLA inserts all-gathers of ~T·M per layer
+    dispatch buffer — XLA inserts all-gathers of ~T·M per layer
     per direction (the dominant collective for MoE train cells). Here each
     (pod, data) shard dispatches only its own tokens, and the model axis
     contributes per-expert partial outputs combined with ONE psum of the
@@ -137,7 +152,7 @@ def _moe_shard_map(p: Dict, x: jax.Array, cfg: ModelConfig):
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or "model" not in mesh.shape:
-        return None, None
+        return None
     from jax.sharding import PartitionSpec as P
 
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape
@@ -150,7 +165,7 @@ def _moe_shard_map(p: Dict, x: jax.Array, cfg: ModelConfig):
         wi_spec = P("model", None, None)
     else:
         if cfg.moe_d_ff % mesh.shape["model"]:
-            return None, None
+            return None
         wi_spec = P(None, None, "model")
     wo_spec = P(wi_spec[0], wi_spec[2], None)
     bspec = batch_axes if len(batch_axes) > 1 else (
@@ -165,21 +180,23 @@ def _moe_shard_map(p: Dict, x: jax.Array, cfg: ModelConfig):
         xt = x_l.reshape(Bl * S, M)
         E_loc = wi.shape[0]
         offset = (jax.lax.axis_index("model") * E_loc) if ep else 0
-        y, aux = _dispatch_core(xt, {"router": router}, cfg, offset, E_loc,
-                                wi, wg, wo)
+        y, aux, held = _dispatch_core(xt, {"router": router}, cfg, offset,
+                                      E_loc, wi, wg, wo)
         if has_shared:
             swi, swg, swo = shared
             y = y + swiglu(xt, swi, swg, swo).astype(jnp.float32)
         y = jax.lax.psum(y, "model")
         if batch_axes:
             aux = jax.lax.pmean(aux, batch_axes)
-        return y.reshape(Bl, S, M).astype(x_l.dtype), aux
+        return (y.reshape(Bl, S, M).astype(x_l.dtype), aux,
+                held.reshape(Bl, S, E_loc))
 
     args = [x, p["router"], p["wi"], p["wg"], p["wo"]]
     in_specs = [P(bspec), P(), wi_spec, wi_spec, wo_spec]
     if has_shared:
         args += [p["shared_wi"], p["shared_wg"], p["shared_wo"]]
         in_specs += list(sh_specs)
-    out = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                        out_specs=(P(bspec), P()))(*args)
-    return out
+    # each EP shard counts its own experts' assignments
+    held_spec = P(bspec, None, "model") if ep else P(bspec)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=(P(bspec), P(), held_spec))(*args)

@@ -3,7 +3,11 @@
 Block = pre-norm mixer (attn | ssm | hybrid-parallel) + pre-norm FFN
 (dense | MoE). Parameters of all layers are stacked on a leading "layers"
 axis so the stack is one `lax.scan` — small HLO, fast compiles, and remat
-policy applies per-layer.
+policy applies per-layer. An MoE model's ``first_k_dense`` leading
+layers have a dense FFN of width ``d_ff``: they are stacked apart
+(``dense_blocks``) and run before the scan over the MoE blocks. Layer
+key i draws layer i, whatever its kind, and every per-layer cache is one
+stack over all layers.
 """
 
 from __future__ import annotations
@@ -27,7 +31,16 @@ PyTree = Any
 # init
 # ---------------------------------------------------------------------------
 
-def init_block(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
+def _ffn(cfg: ModelConfig, dense: bool) -> Optional[str]:
+    """The FFN of a layer: "moe", "mlp" or None. ``dense`` marks one of
+    an MoE model's leading dense layers."""
+    if cfg.uses_moe and not dense:
+        return "moe"
+    return "mlp" if cfg.d_ff else None
+
+
+def init_block(key: jax.Array, cfg: ModelConfig, specs: SpecTree,
+               dense: bool = False) -> Dict:
     ks = jax.random.split(key, 4)
     p: Dict = {"norm_mixer": init_norm(cfg.d_model, specs, "norm_mixer"),
                "norm_ffn": init_norm(cfg.d_model, specs, "norm_ffn")}
@@ -38,30 +51,31 @@ def init_block(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
             p["attn"] = attn_mod.init_attention(ks[0], cfg, specs)
     if cfg.uses_ssm:
         p["ssm"] = ssm_mod.init_ssm(ks[1], cfg, specs)
-    if cfg.uses_moe:
+    ffn = _ffn(cfg, dense)
+    if ffn == "moe":
         p["moe"] = moe_mod.init_moe(ks[2], cfg, specs)
-    elif cfg.d_ff:
+    elif ffn == "mlp":
         p["mlp"] = init_mlp(ks[3], cfg.d_model, cfg.d_ff, specs)
     return p
 
 
 def init_stack(key: jax.Array, cfg: ModelConfig) -> Tuple[Dict, Dict]:
     """Returns (params, logical_specs) with block params stacked on axis 0."""
-    specs = SpecTree()
-    block_specs = SpecTree()
+    def stacked(layer_keys, dense):
+        """Blocks of ``layer_keys`` stacked on a leading "layers" axis,
+        and their specs (recorded while their shapes are traced)."""
+        spec_obj = SpecTree()
+        jax.eval_shape(lambda k: init_block(k, cfg, spec_obj, dense),
+                       layer_keys[0])
+        specs = jax.tree.map(lambda axes: ("layers",) + tuple(axes),
+                             spec_obj.specs,
+                             is_leaf=lambda x: isinstance(x, tuple))
+        return jax.vmap(lambda k: init_block(k, cfg, SpecTree(), dense))(
+            layer_keys), specs
 
-    def one(k):
-        s = SpecTree()
-        p = init_block(k, cfg, s)
-        return p, s
-
+    nd = cfg.first_k_dense
     keys = jax.random.split(key, cfg.num_layers + 3)
-    blocks, s0 = jax.vmap(lambda k: one(k)[0])(keys[: cfg.num_layers]), None
-    # capture specs once (same structure every layer), prefixing "layers"
-    _, spec_obj = one(keys[0])
-    block_axis_specs = jax.tree.map(
-        lambda axes: ("layers",) + tuple(axes),
-        spec_obj.specs, is_leaf=lambda x: isinstance(x, tuple))
+    blocks, block_axis_specs = stacked(keys[nd: cfg.num_layers], False)
 
     ek, uk = keys[-2], keys[-1]
     from .layers import param  # local import to avoid cycle noise
@@ -77,6 +91,9 @@ def init_stack(key: jax.Array, cfg: ModelConfig) -> Tuple[Dict, Dict]:
                                   ("embed", "vocab"), top, "unembed")
     spec_tree = dict(top.specs)
     spec_tree["blocks"] = block_axis_specs
+    if nd:
+        params["dense_blocks"], spec_tree["dense_blocks"] = stacked(
+            keys[:nd], True)
     return params, spec_tree
 
 
@@ -85,7 +102,8 @@ def init_stack(key: jax.Array, cfg: ModelConfig) -> Tuple[Dict, Dict]:
 # ---------------------------------------------------------------------------
 
 def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig,
-                  positions: jax.Array, collect_cache: bool = False):
+                  positions: jax.Array, collect_cache: bool = False,
+                  dense: bool = False):
     """Returns (x_out, aux_loss, cache_piece-or-None)."""
     aux = jnp.zeros((), jnp.float32)
     piece: Dict = {}
@@ -112,13 +130,27 @@ def block_forward(p: Dict, x: jax.Array, cfg: ModelConfig,
         mixed = 0.5 * (mixed + s) if cfg.mixer == "hybrid" else mixed + s
     x = x + mixed
     h = rms_norm(x, p["norm_ffn"], cfg.norm_eps)
-    if cfg.uses_moe:
-        y, aux = moe_mod.moe_apply(p["moe"], h, cfg)
-    elif cfg.d_ff:
+    ffn = _ffn(cfg, dense)
+    if ffn == "moe":
+        y, aux, held = moe_mod.moe_apply(p["moe"], h, cfg)
+        piece["moe"] = _routing_counts(held.sum(1))
+    elif ffn == "mlp":
         y = apply_mlp(p["mlp"], h)
     else:
         y = jnp.zeros_like(h)
+    if cfg.uses_moe and dense:
+        piece["moe"] = _routing_counts(
+            jnp.zeros((x.shape[0], cfg.experts_held), jnp.int32))
     return x + y, aux, (piece if collect_cache else None)
+
+
+def _routing_counts(assign: jax.Array) -> Dict:
+    """An MoE layer's cache piece: ``assign`` (B, experts held), each
+    sequence's assignments to each held expert so far, and ``load_max``,
+    the most assignments one held expert took in one decode step so far
+    (the same in every sequence's row; none yet)."""
+    return {"assign": assign.astype(jnp.int32),
+            "load_max": jnp.zeros_like(assign, jnp.int32)}
 
 
 def forward(params: Dict, tokens_or_embeds: jax.Array, cfg: ModelConfig,
@@ -134,16 +166,23 @@ def forward(params: Dict, tokens_or_embeds: jax.Array, cfg: ModelConfig,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
 
-    def scan_fn(carry, layer_params):
-        x, aux = carry
-        x, a, piece = block_forward(layer_params, x, cfg, positions,
-                                    collect_cache=collect_cache)
-        return (x, aux + a), piece
+    def layers(dense):
+        def scan_fn(carry, layer_params):
+            x, aux = carry
+            x, a, piece = block_forward(layer_params, x, cfg, positions,
+                                        collect_cache=collect_cache,
+                                        dense=dense)
+            return (x, aux + a), piece
+        return jax.checkpoint(scan_fn) if remat == "full" else scan_fn
 
-    if remat == "full":
-        scan_fn = jax.checkpoint(scan_fn)
-    (x, aux), cache = jax.lax.scan(scan_fn, (x, jnp.zeros((), jnp.float32)),
-                                   params["blocks"])
+    carry = (x, jnp.zeros((), jnp.float32))
+    if cfg.first_k_dense:
+        carry, lead = jax.lax.scan(layers(True), carry,
+                                   params["dense_blocks"])
+    (x, aux), cache = jax.lax.scan(layers(False), carry, params["blocks"])
+    if collect_cache and cfg.first_k_dense:
+        cache = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), lead,
+                             cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:
@@ -198,6 +237,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
             cache["attn"] = stack(lambda: attn_mod.init_kv_cache(cfg, batch, max_len, dtype))
     if cfg.uses_ssm:
         cache["ssm"] = stack(lambda: ssm_mod.init_ssm_cache(cfg, batch))
+    if cfg.uses_moe:
+        cache["moe"] = stack(lambda: _routing_counts(
+            jnp.zeros((batch, cfg.experts_held), jnp.int32)))
     return cache
 
 
@@ -211,15 +253,19 @@ def cache_specs(cfg: ModelConfig) -> Dict:
             specs["attn"] = attn_mod.kv_cache_specs()
     if cfg.uses_ssm:
         specs["ssm"] = ssm_mod.ssm_cache_specs()
+    if cfg.uses_moe:
+        specs["moe"] = {"assign": ("layers", "batch", None),
+                        "load_max": ("layers", "batch", None)}
     return specs
 
 
 def block_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
-                 cfg: ModelConfig, cur_index: jax.Array
+                 cfg: ModelConfig, cur_index: jax.Array, dense: bool = False
                  ) -> Tuple[jax.Array, Dict]:
     """One layer's decode against the layer-stacked cache. Attention and
     MLA write their new row into the stack at ``layer``; the SSM state
-    is the whole state, so the layer's is written back whole."""
+    is the whole state, so the layer's is written back whole; an MoE
+    layer adds its step's routing to its counts."""
     cache = dict(cache)
     h = rms_norm(x, p["norm_mixer"], cfg.norm_eps)
     mixed = jnp.zeros_like(x)
@@ -240,9 +286,16 @@ def block_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
         mixed = 0.5 * (mixed + s) if cfg.mixer == "hybrid" else mixed + s
     x = x + mixed
     h = rms_norm(x, p["norm_ffn"], cfg.norm_eps)
-    if cfg.uses_moe:
-        y, _ = moe_mod.moe_apply(p["moe"], h, cfg)
-    elif cfg.d_ff:
+    ffn = _ffn(cfg, dense)
+    if ffn == "moe":
+        y, _, held = moe_mod.moe_apply(p["moe"], h, cfg)
+        held = held[:, 0]                                    # (B, held)
+        counts = cache["moe"]
+        cache["moe"] = {
+            "assign": counts["assign"].at[layer].add(held),
+            "load_max": counts["load_max"].at[layer].max(
+                held.sum(0)[None, :])}
+    elif ffn == "mlp":
         y = apply_mlp(p["mlp"], h)
     else:
         y = jnp.zeros_like(h)
@@ -264,14 +317,22 @@ def decode_step(params: Dict, cache: Dict, token_or_embed: jax.Array,
     else:
         x = token_or_embed[:, None, :].astype(params["embed"].dtype)
 
-    def scan_fn(carry, inp):
-        x, cache = carry
-        layer, layer_params = inp
-        return block_decode(layer_params, x, cache, layer, cfg,
-                            cur_index), None
+    def layers(dense):
+        def scan_fn(carry, inp):
+            x, cache = carry
+            layer, layer_params = inp
+            return block_decode(layer_params, x, cache, layer, cfg,
+                                cur_index, dense), None
+        return scan_fn
 
+    nd = cfg.first_k_dense
+    carry = (x, cache)
+    if nd:
+        carry, _ = jax.lax.scan(layers(True), carry,
+                                (jnp.arange(nd), params["dense_blocks"]))
     (x, cache), _ = jax.lax.scan(
-        scan_fn, (x, cache), (jnp.arange(cfg.num_layers), params["blocks"]))
+        layers(False), carry,
+        (jnp.arange(nd, cfg.num_layers), params["blocks"]))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params.get("unembed")
     if unembed is None:

@@ -67,7 +67,7 @@ def test_decode_matches_forward(arch):
     from repro.configs import replace
     cfg = get_reduced(arch)
     if cfg.num_experts:
-        cfg = replace(cfg, top_k=cfg.num_experts, capacity_factor=2.0)
+        cfg = replace(cfg, top_k=cfg.num_experts)
     params, _ = init_stack(jax.random.PRNGKey(1), cfg)
     B, S = 1, 24
     tokens = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0,
@@ -99,7 +99,7 @@ def test_decode_at_staggered_positions_matches_each_sequence_alone(arch):
     from repro.configs import replace
     cfg = get_reduced(arch)
     if cfg.num_experts:       # continuous gating: see the test above
-        cfg = replace(cfg, top_k=cfg.num_experts, capacity_factor=2.0)
+        cfg = replace(cfg, top_k=cfg.num_experts)
     if cfg.window is not None:
         cfg = replace(cfg, window=8)
     params, _ = init_stack(jax.random.PRNGKey(1), cfg)
