@@ -15,6 +15,7 @@ job's summary of its spans and counters is printed at its end.
 from __future__ import annotations
 
 import argparse
+import collections
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -34,6 +35,10 @@ KV_HEAP_PAGES = 1024
 # the most prompt tokens one prefill pass takes: a larger batch is
 # prefilled in groups of whole sequences, which bounds its transients
 PREFILL_TOKENS = 8192
+# how many steps later than its own a decode step's token is read: one
+# keeps the next step queued on the device while the host waits on a
+# token, and two measured no faster on a TPU v5e
+READ_LAG = 1
 # the KV tier's counters (its snapshot) that a job's record takes
 KV_COUNTERS = ("rows_appended", "pages_spilled", "bytes_spilled",
                "pages_fetched", "bytes_fetched")
@@ -245,7 +250,11 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
                 for b in range(B):
                     paged.add_sequence(b)
 
+        # a step's token is read READ_LAG steps later, once the next
+        # step is dispatched; the last step reads all that are left
         out_tokens = []
+        unread: collections.deque = collections.deque()
+        reads_ready = 0
         first_logits = None
         with trace.span("serve.decode") as decoding:
             for i in range(args.gen):
@@ -253,17 +262,27 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
                     with trace.span("serve.decode.dispatch"):
                         logits, cache, tok, cur = step_fn(params, cache, tok,
                                                           cur)
+                        if not cfg.frontend:
+                            tok.copy_to_host_async()
                     if i == 0:
                         first_logits = logits
                     if not cfg.frontend:
+                        unread.append(tok)
+                        keep = READ_LAG if i < args.gen - 1 else 0
                         with trace.span("serve.decode.token_read"):
-                            out_tokens.append(np.asarray(tok))
+                            while len(unread) > keep:
+                                done = unread.popleft()
+                                reads_ready += done.is_ready()
+                                out_tokens.append(np.asarray(done))
                     if paged is not None:
                         kv_rows = rng.normal(
                             size=(B, kv_features)).astype(np.float32)
                         for b in range(B):
                             paged.append_tokens(b, kv_rows[b : b + 1])
             jax.block_until_ready(cache)
+            if not cfg.frontend:
+                decoding.add("decode.reads_ready", reads_ready)
+                decoding.add("decode.read_lag", READ_LAG)
         out["decode_tok_s"] = args.gen * B / trace.seconds(decoding)
         if "moe" in cache:
             # the routing the device counted, read once the window is over

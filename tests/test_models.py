@@ -166,19 +166,23 @@ def test_serving_step_takes_the_cache_donated_and_writes_rows():
         a.nbytes for a in leaves)
 
 
+def splice(cache, pcache):
+    """The prefill's cache written into the front of a longer decode
+    cache (leaves stacked ``(L, B, S, ...)``)."""
+    return jax.tree.map(
+        lambda full, part: full.at[:, :, :part.shape[2]].set(
+            part.astype(full.dtype)) if full.ndim >= 3 and
+        part.shape[2] <= full.shape[2] else part.astype(full.dtype),
+        cache, pcache)
+
+
 def test_prefill_then_decode_continues():
     cfg = get_reduced("qwen1.5-0.5b")
     params, _ = init_stack(KEY, cfg)
     B, S = 2, 32
     tokens = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
     last, pcache = prefill(params, tokens[:, :S], cfg)
-    # splice prefill cache into a longer decode cache
-    cache = init_cache(cfg, B, max_len=S + 8)
-    cache = jax.tree.map(
-        lambda full, part: full.at[:, :, :part.shape[2]].set(
-            part.astype(full.dtype)) if full.ndim >= 3 and
-        part.shape[2] <= full.shape[2] else part.astype(full.dtype),
-        cache, pcache)
+    cache = splice(init_cache(cfg, B, max_len=S + 8), pcache)
     logits, _ = decode_step(params, cache, tokens[:, S],
                             jnp.full((B,), S, jnp.int32), cfg)
     full_logits, _ = forward(params, tokens, cfg)
@@ -205,3 +209,47 @@ def test_param_count_analytic_close_to_actual():
         analytic = cfg.param_count()
         # padded vocab + small norms: within 20%
         assert abs(actual - analytic) / actual < 0.2, arch
+
+
+@pytest.mark.parametrize("argv", [
+    ["--arch", "qwen1.5-0.5b", "--spill"],
+    ["--arch", "deepseek-v2-lite-16b", "--expert-parallel", "2"],
+], ids=["qwen1.5-0.5b", "deepseek-v2-lite-16b-ep2"])
+def test_served_tokens_match_a_plain_greedy_loop(argv, tmp_path, monkeypatch):
+    """The tokens ``serve.run`` stacks, however late its loop reads them,
+    are those of a plain loop over ``serve.programs`` that reads each
+    step's token as soon as the step is called, in the same order."""
+    import types
+
+    from repro.launch import serve
+    from repro.launch.mesh import make_local_mesh
+    B, P, G = 3, 12, 7
+    stacked = []
+
+    def stack(arrays, *a, **kw):
+        out = np.stack(arrays, *a, **kw)
+        stacked.append(out)
+        return out
+
+    tapped_np = types.ModuleType(np.__name__)
+    tapped_np.__dict__.update(vars(np), stack=stack)
+    monkeypatch.setattr(serve, "np", tapped_np)
+    # serve.run keeps its compile cache in the checkout unless told
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    out = serve.run(argv + ["--reduced", "--batch", str(B),
+                            "--prompt-len", str(P), "--gen", str(G)])
+    served = next(a for a in reversed(stacked) if a.shape == (B, G))
+
+    cfg, params, prompts = out["cfg"], out["params"], out["prompts"]
+    prefill_jit, step_jit, pick = serve.programs(cfg)
+    want = []
+    with jax.set_mesh(make_local_mesh(1, 1)):
+        logits, pcache = prefill_jit(params, prompts)
+        cache = splice(init_cache(cfg, B, max_len=P + G), pcache)
+        tok = pick(logits, jnp.zeros((B,), jnp.int32))
+        assert np.array_equal(tok, out["first_token"])
+        cur = jnp.full((B,), P, jnp.int32)
+        for _ in range(G):
+            _, cache, tok, cur = step_jit(params, cache, tok, cur)
+            want.append(np.asarray(tok))
+    assert np.array_equal(served, np.stack(want, axis=1))

@@ -2,9 +2,11 @@
 
 import gc
 import threading
+import types
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
 from repro import trace
@@ -257,3 +259,61 @@ def test_spans_land_on_the_profilers_host_plane(compile_cache, tmp_path):
                 assert abs(e.duration_ns - ns) <= 0.05 * ns, (name, e, ns)
             assert abs(start + e.start_ns
                        - (s.start_ns + job.wall_offset_ns)) < 1e6, name
+
+
+def test_decode_dispatches_the_next_step_before_it_reads_a_token(
+        compile_cache, monkeypatch):
+    """The decode loop reads step i's token only after it has dispatched
+    step i + 1, so the device has a step queued while the host waits on
+    a token; every token is read once, in step order. The compiled step
+    and numpy's ``asarray`` are wrapped in the serve module, as the chip
+    benchmark's tap wraps numpy there, and record what they were given."""
+    events, toks = [], []
+
+    class Tapped:
+        """The step program, as serve.run lowers, compiles and calls it."""
+
+        def __init__(self, f):
+            self.f = f
+
+        def __getattr__(self, name):
+            return getattr(self.f, name)
+
+        def lower(self, *a):
+            return Tapped(self.f.lower(*a))
+
+        def compile(self):
+            return Tapped(self.f.compile())
+
+        def __call__(self, *a):
+            out = self.f(*a)
+            events.append(("dispatch", len(toks)))
+            toks.append(out[2])
+            return out
+
+    def asarray(a, *args, **kw):
+        step = next((i for i, t in enumerate(toks) if t is a), None)
+        if step is not None:
+            events.append(("read", step))
+        return np.asarray(a, *args, **kw)
+
+    programs = serve.programs
+
+    def tapped_programs(cfg):
+        prefill_jit, step_jit, pick = programs(cfg)
+        return prefill_jit, Tapped(step_jit), pick
+
+    tapped_np = types.ModuleType(np.__name__)
+    tapped_np.__dict__.update(vars(np), asarray=asarray)
+    monkeypatch.setattr(serve, "programs", tapped_programs)
+    monkeypatch.setattr(serve, "np", tapped_np)
+    _, job = serve_job(SERVE_ARGV)
+
+    assert [i for kind, i in events if kind == "dispatch"] == list(range(GEN))
+    assert [i for kind, i in events if kind == "read"] == list(range(GEN))
+    at = {e: n for n, e in enumerate(events)}
+    for i in range(GEN - 1):
+        assert at[("dispatch", i + 1)] < at[("read", i)], events
+    counts = job.named("serve.decode")[0].counts
+    assert counts["decode.read_lag"] == serve.READ_LAG >= 1
+    assert 0 <= counts["decode.reads_ready"] <= GEN
