@@ -63,13 +63,6 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
-    # --- perf knobs (§Perf iteration; defaults = paper-faithful baseline) ---
-    attn_q_block: int = 512
-    attn_kv_block: int = 512
-    flash_bf16: bool = False         # bf16 operand reads, f32 accumulation
-    swa_sliced_kv: bool = False      # sliding window: slice kv instead of mask
-    moe_shard_map: bool = False      # shard-local MoE dispatch (no all-gather)
-    mla_latent_psum: bool = False    # decode: partial scores + psum, not cache all-gather
     # --- misc ------------------------------------------------------------------
     norm_eps: float = 1e-5
     tie_embeddings: bool = False

@@ -33,11 +33,8 @@ TARGET_KIND = "TPU v5 lite"
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str,
-             run: RunConfig, hlo_dir=None, knobs=None) -> dict:
+             run: RunConfig, hlo_dir=None) -> dict:
     cfg = get_config(arch)
-    if knobs is not None:
-        from repro.configs.optimized import optimize
-        cfg = optimize(cfg, only=knobs if knobs else None)
     shape = SHAPES[shape_name]
     ok, why = cell_supported(cfg, shape)
     if not ok:
@@ -78,24 +75,12 @@ def main() -> None:
     ap.add_argument("--hlo-dir", default=None)
     ap.add_argument("--remat", default="full")
     ap.add_argument("--skip-existing", action="store_true")
-    ap.add_argument("--opt", action="store_true",
-                    help="apply ALL beyond-paper perf knobs (configs.optimized)")
-    ap.add_argument("--knobs", default=None,
-                    help="comma list of individual knobs (see optimized.KNOBS)")
-    ap.add_argument("--variant", default=None,
-                    help="label for this run's result keys")
     args = ap.parse_args()
 
     archs = DRYRUN_ARCHS if (args.all or args.arch is None) else [args.arch]
     shapes = list(SHAPES) if (args.all or args.shape is None) else [args.shape]
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     run = RunConfig(remat=args.remat)
-    knobs = None
-    if args.opt:
-        knobs = set()
-    if args.knobs is not None:
-        knobs = set(k for k in args.knobs.split(",") if k)
-    variant = args.variant or ("opt" if knobs is not None else "base")
 
     out_path = Path(args.out)
     out_path.parent.mkdir(parents=True, exist_ok=True)
@@ -108,14 +93,12 @@ def main() -> None:
     for mesh_kind in meshes:
         for arch in archs:
             for shape_name in shapes:
-                key = (arch, shape_name, mesh_kind, variant)
+                key = (arch, shape_name, mesh_kind)
                 if args.skip_existing and key in results and \
                         results[key].get("status") in ("ok", "skipped"):
                     continue
-                r = run_cell(arch, shape_name, mesh_kind, run, args.hlo_dir,
-                             knobs=knobs)
+                r = run_cell(arch, shape_name, mesh_kind, run, args.hlo_dir)
                 r["key"] = list(key)
-                r["variant"] = variant
                 r["remat"] = args.remat
                 results[key] = r
                 status = r["status"]

@@ -1,12 +1,15 @@
-"""Batched serving driver: prefill → decode against the paged KV tier.
+"""Batched serving driver: prefill → decode, beside a remote KV tier.
 
-Runs the full serving path: contiguous-cache decode for the jitted model
-step, while the host-side PagedKVCache (+ RDMAbox remote spill) manages
-per-sequence KV pages with run-coalesced gathers — the paper's node-level
-abstraction serving an LLM. Prefill and the decode step are compiled
-before the timed windows; the device they ran on is printed with the
-rates. Each layer boundary of a job is a span of ``repro.trace``; the
-job's summary of its spans and counters is printed at its end.
+The jitted model step decodes against the contiguous stacked cache on
+the device (``models.init_cache``), into which the prefill's cache is
+spliced. With ``--spill``, the host-side KV tier (``box.KVStore`` over
+RDMAbox) takes one random 64-wide row per sequence and step, not the
+model's K and V, and every sequence's pages are spilled to the donors
+and fetched back at the job's end. Prefill and the decode step are
+compiled before the timed windows; the device they ran on is printed
+with the rates. Each layer boundary of a job is a span of
+``repro.trace``; the job's summary of its spans and counters is printed
+at its end.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced \
       --batch 4 --prompt-len 64 --gen 32
@@ -26,7 +29,8 @@ from repro import box, trace
 from repro.configs import get_config, get_reduced, replace
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import device_info, make_local_mesh
-from repro.models import decode_step, init_cache, init_stack, prefill
+from repro.models import (decode_step, init_cache, init_stack, prefill,
+                          splice_prefill)
 
 # pages reserved per client for the KV spill arena (the heap slice of
 # each donor region), at least; the rest of the slice backs background
@@ -207,19 +211,10 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
             jax.block_until_ready(pcache)
         out["prefill_s"] = trace.seconds(prefilling)
 
-        def splice_leaf(full, part):
-            # cache leaves are stacked (L, B, ...); match on trailing dims
-            if full.shape == part.shape:
-                return part.astype(full.dtype)
-            if full.ndim >= 3 and part.ndim == full.ndim and \
-                    part.shape[2] <= full.shape[2]:
-                return full.at[:, :, :part.shape[2]].set(part.astype(full.dtype))
-            return part.astype(full.dtype)
-
         with trace.span("serve.splice"):
             if "moe" in pcache:          # read after the decode window
                 held_prefill = jnp.sum(pcache["moe"]["assign"])
-            cache = jax.tree.map(splice_leaf, cache, pcache)
+            cache = splice_prefill(cache, pcache)
             tok = pick(logits, tok)
         out.update(params=params, prompts=prompts, first_token=tok)
         print(f"prefill {args.prompt_len} tokens × {B} seqs in "

@@ -4,15 +4,6 @@ The training path is a pure-jnp online-softmax implementation (nested scan
 over query/key blocks) so the full S×S score matrix is never materialized —
 required for prefill_32k to fit HBM. The Pallas kernel in
 ``repro.kernels.flash_attention`` is the TPU drop-in with the same oracle.
-
-Perf knobs (ModelConfig, §Perf iterations; defaults = baseline):
-  attn_q_block / attn_kv_block — tile sizes (bigger ⇒ fewer carry
-      read/writes of the (m, l, acc) online-softmax state);
-  flash_bf16 — keep q/k/v operands bf16 and accumulate in f32 via
-      preferred_element_type (halves score-path operand bytes);
-  swa_sliced_kv — sliding-window attention reads a fixed
-      (window + q_block) KV slice per q block instead of masking the full
-      sequence (compute & bytes ∝ window, not S).
 """
 
 from __future__ import annotations
@@ -66,25 +57,20 @@ def flash_attention_jnp(
     q: jax.Array, k: jax.Array, v: jax.Array,
     *, causal: bool = True, window: Optional[int] = None,
     q_block: int = 512, kv_block: int = 512,
-    q_offset: int = 0, bf16_compute: bool = False,
-    swa_sliced_kv: bool = False, scale: Optional[float] = None,
+    q_offset: int = 0, scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax attention.
 
     q: (B, Sq, H, D); k, v: (B, Skv, Kh, D) with H a multiple of Kh.
     Never materializes more than (q_block × kv_block) scores per (B, head).
     ``q_offset`` positions q tokens at ``q_offset + i`` against kv.
-    Scores are scaled by ``scale`` (default D^-0.5).
+    Scores are scaled by ``scale`` (default D^-0.5). Operands are read
+    in float32.
     """
     B, Sq, H, D = q.shape
     _, Skv, Kh, _ = k.shape
     G = H // Kh
     scale = D ** -0.5 if scale is None else scale
-    op_dtype = q.dtype if bf16_compute else jnp.float32
-
-    if window is not None and swa_sliced_kv and Skv > window + q_block:
-        return _flash_swa_sliced(q, k, v, window=window, q_block=q_block,
-                                 q_offset=q_offset, bf16_compute=bf16_compute)
 
     q_block = min(q_block, Sq)
     kv_block = min(kv_block, Skv)
@@ -107,13 +93,14 @@ def flash_attention_jnp(
     def q_step(_, qi_blk):
         qi, qblk = qi_blk                     # index scalar, (B,qb,Kh,G,D)
         q_pos = q_offset + qi * q_block + q_pos_base          # (qb,)
-        qc = qblk.astype(op_dtype)
+        qc = qblk.astype(jnp.float32)
 
         def kv_step(carry, kj_blk):
             m, l, acc = carry
             kj, kblk, vblk = kj_blk
             kv_pos = kj * kv_block + kv_pos_base              # (kb,)
-            s = jnp.einsum("bqkgd,btkd->bkgqt", qc, kblk.astype(op_dtype),
+            s = jnp.einsum("bqkgd,btkd->bkgqt", qc,
+                           kblk.astype(jnp.float32),
                            preferred_element_type=jnp.float32) * scale
             mask = kv_pos[None, :] <= (Skv - 1)  # kv padding
             if causal:
@@ -126,8 +113,8 @@ def flash_attention_jnp(
             corr = jnp.exp(m - m_new)
             l_new = l * corr + p.sum(axis=-1)
             acc_new = acc * corr[..., None] + jnp.einsum(
-                "bkgqt,btkd->bkgqd", p.astype(op_dtype),
-                vblk.astype(op_dtype), preferred_element_type=jnp.float32)
+                "bkgqt,btkd->bkgqd", p, vblk.astype(jnp.float32),
+                preferred_element_type=jnp.float32)
             return (m_new, l_new, acc_new), None
 
         m0 = jnp.full((B, Kh, G, q_block), NEG_INF, jnp.float32)
@@ -145,77 +132,23 @@ def flash_attention_jnp(
     return out[:, :Sq].astype(q.dtype)
 
 
-def _flash_swa_sliced(q, k, v, *, window: int, q_block: int, q_offset: int,
-                      bf16_compute: bool):
-    """Sliding-window attention with a fixed-size KV slice per q block.
-
-    Every q block attends to exactly [start, start + window + q_block) where
-    start = block_start − window: a *static-size* dynamic_slice, so compute
-    and bytes scale with the window, not the sequence (the masked baseline
-    wastes S/window).
-    """
-    B, Sq, H, D = q.shape
-    _, Skv, Kh, _ = k.shape
-    G = H // Kh
-    scale = D ** -0.5
-    op_dtype = q.dtype if bf16_compute else jnp.float32
-    q_block = min(q_block, Sq)
-    assert Sq % q_block == 0, "SWA sliced path expects q_block | Sq"
-    nq = Sq // q_block
-    span = window + q_block
-    # pad kv on the left by `window` so every slice is in range
-    kp = jnp.pad(k, ((0, 0), (window, 0), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (window, 0), (0, 0), (0, 0)))
-    qb = q.reshape(B, nq, q_block, Kh, G, D).transpose(1, 0, 2, 3, 4, 5)
-
-    q_pos_base = jnp.arange(q_block)
-    kv_pos_base = jnp.arange(span)
-
-    def q_step(_, qi_blk):
-        qi, qblk = qi_blk
-        # kv tokens [qi·qb − window, qi·qb + qb) in original coordinates
-        start = qi * q_block                     # index into left-padded kv
-        ks = jax.lax.dynamic_slice(kp, (0, start, 0, 0),
-                                   (B, span, Kh, D))
-        vs = jax.lax.dynamic_slice(vp, (0, start, 0, 0),
-                                   (B, span, Kh, D))
-        q_pos = q_offset + qi * q_block + q_pos_base            # (qb,)
-        kv_pos = qi * q_block - window + kv_pos_base            # (span,)
-        s = jnp.einsum("bqkgd,btkd->bkgqt", qblk.astype(op_dtype),
-                       ks.astype(op_dtype),
-                       preferred_element_type=jnp.float32) * scale
-        mask = (kv_pos[None, :] >= 0) & (kv_pos[None, :] <= q_pos[:, None]) \
-            & (kv_pos[None, :] > q_pos[:, None] - window)
-        s = jnp.where(mask[None, None, None], s, NEG_INF)
-        m = s.max(axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        out = jnp.einsum("bkgqt,btkd->bkgqd", p.astype(op_dtype),
-                         vs.astype(op_dtype),
-                         preferred_element_type=jnp.float32)
-        out = out / jnp.maximum(p.sum(-1), 1e-30)[..., None]
-        return None, out.transpose(0, 3, 1, 2, 4)     # (B,qb,Kh,G,D)
-
-    _, outs = jax.lax.scan(q_step, None, (jnp.arange(nq), qb))
-    return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, H, D).astype(q.dtype)
-
-
 def attention_train(p: Dict, x: jax.Array, cfg: ModelConfig,
                     positions: jax.Array, return_kv: bool = False):
     q, k, v = qkv_proj(p, x, cfg, positions)
-    out = flash_attention_jnp(
-        q, k, v, causal=True, window=cfg.window,
-        q_block=cfg.attn_q_block, kv_block=cfg.attn_kv_block,
-        bf16_compute=cfg.flash_bf16, swa_sliced_kv=cfg.swa_sliced_kv)
+    out = flash_attention_jnp(q, k, v, causal=True, window=cfg.window)
     B, S = x.shape[:2]
     out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
     y = jnp.einsum("bsh,hm->bsm", out, p["wo"])
     if not return_kv:
         return y
-    # flat-layout cache piece for decode continuation (ring-windowed archs
-    # keep the last `window` positions)
+    # flat-layout cache piece for decode continuation. A sliding-window
+    # arch keeps the last `window` positions in decode's ring order:
+    # position t at slot t % window
     Kh, D = cfg.num_kv_heads, cfg.head_dim
-    if cfg.window is not None and S > cfg.window:
-        k, v = k[:, -cfg.window:], v[:, -cfg.window:]
+    W = cfg.window
+    if W is not None and S > W:
+        k = jnp.roll(k[:, -W:], S % W, axis=1)
+        v = jnp.roll(v[:, -W:], S % W, axis=1)
     return y, {"k": k.reshape(B, -1, Kh * D).astype(jnp.bfloat16),
                "v": v.reshape(B, -1, Kh * D).astype(jnp.bfloat16)}
 

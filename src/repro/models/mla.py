@@ -140,12 +140,6 @@ def mla_decode(p: Dict, x: jax.Array, cache: Dict, layer: jax.Array,
     # absorb W_kb into the query: q_lat (B,H,R)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0].astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    if cfg.mla_latent_psum:
-        # §Perf: shard q_lat's R dim like the cached latent so the scores
-        # contraction becomes partial-R + psum of (B,H,S) instead of an
-        # all-gather of the 100+ GB latent cache (40x fewer bytes).
-        from jax.sharding import PartitionSpec as P
-        q_lat = jax.lax.with_sharding_constraint(q_lat, P(None, None, "model"))
     s = jnp.einsum("bhr,bsr->bhs", q_lat, c_kv.astype(jnp.float32))
     s += jnp.einsum("bhd,bsd->bhs", q_pe[:, 0].astype(jnp.float32),
                     k_pe.astype(jnp.float32))

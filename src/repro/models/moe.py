@@ -63,16 +63,15 @@ def init_moe(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
     return p
 
 
-def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig,
-                   expert_offset, num_local_experts: int,
-                   wi, wg, wo) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Dropless dispatch of ``xt`` (T, M) to the ``E_loc`` experts whose
-    weights are in wi/wg/wo, global ids ``expert_offset ..`` (EP slice).
-    Returns (y (T, M) f32 partial, aux, held (T, E_loc) int32: how many
-    of each token's top-k went to each held expert, 0 or 1)."""
+def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless dispatch of ``xt`` (T, M) to the ``E_loc`` held experts,
+    global ids ``cfg.expert_offset ..`` (EP slice). Returns (y (T, M) f32
+    partial, aux, held (T, E_loc) int32: how many of each token's top-k
+    went to each held expert, 0 or 1)."""
     T, M = xt.shape
     E, K = cfg.num_experts, cfg.top_k
-    E_loc = num_local_experts
+    E_loc = cfg.experts_held
 
     logits = jnp.einsum("tm,me->te", xt.astype(jnp.float32), p["router"])
     probs = jax.nn.softmax(logits, axis=-1)                       # (T, E)
@@ -87,7 +86,7 @@ def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig,
     aux = cfg.router_aux_weight * E * jnp.sum(me * ce)
 
     # ---- sort the held assignments by expert: one row each ----
-    local_e = expert_idx.reshape(-1) - expert_offset              # (T*K,)
+    local_e = expert_idx.reshape(-1) - cfg.expert_offset          # (T*K,)
     in_slice = (local_e >= 0) & (local_e < E_loc)
     flat_e = jnp.where(in_slice, local_e, E_loc)                  # E_loc = out
     rows = T * min(K, E_loc)              # a token sends ≤ min(K, E_loc) here
@@ -97,9 +96,9 @@ def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig,
     token_of = order // K                                         # (rows,)
 
     grouped = xt[token_of]                                        # (rows, M)
-    h = jax.lax.ragged_dot(grouped, wi, sizes)
-    g = jax.lax.ragged_dot(grouped, wg, sizes)
-    yg = jax.lax.ragged_dot(h * jax.nn.silu(g), wo, sizes)        # (rows, M)
+    h = jax.lax.ragged_dot(grouped, p["wi"], sizes)
+    g = jax.lax.ragged_dot(grouped, p["wg"], sizes)
+    yg = jax.lax.ragged_dot(h * jax.nn.silu(g), p["wo"], sizes)   # (rows, M)
 
     w = jnp.where(held, gate.reshape(-1)[order], 0.0)
     y = jnp.zeros((T, M), jnp.float32).at[token_of].add(
@@ -113,90 +112,12 @@ def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, M) → (out, aux_loss, held (B, S, experts held): each
     token's assignments to each held expert)."""
-    if cfg.moe_shard_map:
-        out = _moe_shard_map(p, x, cfg)
-        if out is not None:
-            return out
     B, S, M = x.shape
     xt = x.reshape(B * S, M)
-    E_loc = cfg.experts_held
-    y, aux, held = _dispatch_core(xt, p, cfg, cfg.expert_offset, E_loc,
-                                  p["wi"], p["wg"], p["wo"])
+    y, aux, held = _dispatch_core(xt, p, cfg)
     if cfg.num_shared_experts:
         y = y + swiglu(xt, p["shared_wi"], p["shared_wg"],
                        p["shared_wo"]).astype(jnp.float32)
     return (y.reshape(B, S, M).astype(x.dtype), aux,
-            held.reshape(B, S, E_loc))
+            held.reshape(B, S, cfg.experts_held))
 
-
-def _moe_shard_map(p: Dict, x: jax.Array, cfg: ModelConfig):
-    """Shard-local MoE dispatch (§Perf, beyond-paper optimization).
-
-    The global-dispatch path gathers the whole token batch to build the
-    dispatch buffer — XLA inserts all-gathers of ~T·M per layer
-    per direction (the dominant collective for MoE train cells). Here each
-    (pod, data) shard dispatches only its own tokens, and the model axis
-    contributes per-expert partial outputs combined with ONE psum of the
-    (T_local, M) output:
-
-      EP layout (experts sharded over model, e.g. deepseek): every model
-      shard packs/computes only its E/model experts; psum sums disjoint
-      expert contributions.
-      TP layout (expert FFN dim sharded, e.g. qwen2-moe, 60 ∤ 16): every
-      shard computes all experts on an F/model slice; psum sums the partial
-      contractions.
-
-    It is RDMAbox thinking at the collective tier: move the merge
-    (dispatch) next to the data, send one coalesced message (the psum)
-    instead of many fine-grained gathers.
-    """
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or "model" not in mesh.shape:
-        return None
-    from jax.sharding import PartitionSpec as P
-
-    batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape
-                       and x.shape[0] % mesh.shape[a] == 0)
-    rules = dict(cfg.sharding_overrides)
-    E = cfg.num_experts
-    ep = (rules.get("experts", "model") == "model"
-          and E % mesh.shape["model"] == 0)
-    if ep:
-        wi_spec = P("model", None, None)
-    else:
-        if cfg.moe_d_ff % mesh.shape["model"]:
-            return None
-        wi_spec = P(None, None, "model")
-    wo_spec = P(wi_spec[0], wi_spec[2], None)
-    bspec = batch_axes if len(batch_axes) > 1 else (
-        batch_axes[0] if batch_axes else None)
-
-    has_shared = bool(cfg.num_shared_experts)
-    sh_specs = (P(None, "model"), P(None, "model"), P("model", None)) \
-        if has_shared else ()
-
-    def local(x_l, router, wi, wg, wo, *shared):
-        Bl, S, M = x_l.shape
-        xt = x_l.reshape(Bl * S, M)
-        E_loc = wi.shape[0]
-        offset = (jax.lax.axis_index("model") * E_loc) if ep else 0
-        y, aux, held = _dispatch_core(xt, {"router": router}, cfg, offset,
-                                      E_loc, wi, wg, wo)
-        if has_shared:
-            swi, swg, swo = shared
-            y = y + swiglu(xt, swi, swg, swo).astype(jnp.float32)
-        y = jax.lax.psum(y, "model")
-        if batch_axes:
-            aux = jax.lax.pmean(aux, batch_axes)
-        return (y.reshape(Bl, S, M).astype(x_l.dtype), aux,
-                held.reshape(Bl, S, E_loc))
-
-    args = [x, p["router"], p["wi"], p["wg"], p["wo"]]
-    in_specs = [P(bspec), P(), wi_spec, wi_spec, wo_spec]
-    if has_shared:
-        args += [p["shared_wi"], p["shared_wg"], p["shared_wo"]]
-        in_specs += list(sh_specs)
-    # each EP shard counts its own experts' assignments
-    held_spec = P(bspec, None, "model") if ep else P(bspec)
-    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=(P(bspec), P(), held_spec))(*args)

@@ -243,6 +243,22 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache
 
 
+def splice_prefill(cache: Dict, pcache: Dict) -> Dict:
+    """``prefill``'s cache written into the front of an ``init_cache``
+    decode cache, by kind: attention and MLA rows go to the first
+    positions of each layer's slab (a windowed prefill's rows are
+    already in ring order), SSM state and MoE counts are taken whole."""
+    def rows(full, part):
+        return full.at[:, :, :part.shape[2]].set(part.astype(full.dtype))
+
+    def whole(full, part):
+        return part.astype(full.dtype)
+
+    return {kind: jax.tree.map(rows if kind in ("attn", "mla") else whole,
+                               cache[kind], pcache[kind])
+            for kind in cache}
+
+
 def cache_specs(cfg: ModelConfig) -> Dict:
     """Logical-axis tree mirroring init_cache()'s structure."""
     specs: Dict = {}

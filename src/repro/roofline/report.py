@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, List
+from typing import Dict
 
 
 def load(path: str = "results/dryrun.json") -> Dict:
@@ -16,12 +16,12 @@ def fmt_ms(s: float) -> str:
     return f"{s*1e3:,.1f}"
 
 
-def dryrun_table(rows: Dict, mesh: str, variant: str = "base") -> str:
+def dryrun_table(rows: Dict, mesh: str) -> str:
     out = ["| arch | shape | status | bytes/dev (GB) | compile (s) |",
            "|---|---|---|---:|---:|"]
     for key in sorted(rows):
         r = rows[key]
-        if key[2] != mesh or (len(key) > 3 and key[3] != variant):
+        if key[2] != mesh:
             continue
         if r["status"] == "skipped":
             out.append(f"| {r['arch']} | {r['shape']} | SKIP (documented) | — | — |")
@@ -33,13 +33,13 @@ def dryrun_table(rows: Dict, mesh: str, variant: str = "base") -> str:
     return "\n".join(out)
 
 
-def roofline_table(rows: Dict, variant: str = "base") -> str:
+def roofline_table(rows: Dict) -> str:
     out = ["| arch | shape | compute (ms) | memory (ms) | collective (ms) "
            "| dominant | useful-FLOPs | roofline frac |",
            "|---|---|---:|---:|---:|---|---:|---:|"]
     for key in sorted(rows):
         r = rows[key]
-        if key[2] != "single" or key[3] != variant or r["status"] != "ok":
+        if key[2] != "single" or r["status"] != "ok":
             continue
         out.append(
             f"| {r['arch']} | {r['shape']} | {fmt_ms(r['compute_s'])} | "
@@ -48,18 +48,3 @@ def roofline_table(rows: Dict, variant: str = "base") -> str:
             f"{r['roofline_fraction']:.4f} |")
     return "\n".join(out)
 
-
-def variant_compare(rows: Dict, arch: str, shape: str,
-                    variants: List[str]) -> str:
-    out = ["| variant | compute (ms) | memory (ms) | collective (ms) | "
-           "dominant | frac |", "|---|---:|---:|---:|---|---:|"]
-    for v in variants:
-        for mesh in ("single",):
-            r = rows.get((arch, shape, mesh, v))
-            if not r or r["status"] != "ok":
-                continue
-            out.append(f"| {v} | {fmt_ms(r['compute_s'])} | "
-                       f"{fmt_ms(r['memory_s'])} | "
-                       f"{fmt_ms(r['collective_s'])} | {r['dominant']} | "
-                       f"{r['roofline_fraction']:.4f} |")
-    return "\n".join(out)

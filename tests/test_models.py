@@ -7,7 +7,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_reduced
 from repro.models import (decode_step, forward, init_cache, init_stack,
-                          loss_fn, prefill)
+                          loss_fn, prefill, splice_prefill)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -166,27 +166,35 @@ def test_serving_step_takes_the_cache_donated_and_writes_rows():
         a.nbytes for a in leaves)
 
 
-def splice(cache, pcache):
-    """The prefill's cache written into the front of a longer decode
-    cache (leaves stacked ``(L, B, S, ...)``)."""
-    return jax.tree.map(
-        lambda full, part: full.at[:, :, :part.shape[2]].set(
-            part.astype(full.dtype)) if full.ndim >= 3 and
-        part.shape[2] <= full.shape[2] else part.astype(full.dtype),
-        cache, pcache)
-
-
-def test_prefill_then_decode_continues():
-    cfg = get_reduced("qwen1.5-0.5b")
+@pytest.mark.parametrize("arch,window,P", [
+    ("qwen1.5-0.5b", None, 32),
+    ("deepseek-v2-lite-16b", None, 32),
+    ("mamba2-780m", None, 16),
+    ("hymba-1.5b", 8, 5),
+    ("hymba-1.5b", 8, 16),
+    ("hymba-1.5b", 8, 12),
+], ids=["qwen1.5-0.5b", "deepseek-v2-lite-16b", "mamba2-780m",
+        "hymba-1.5b-w8-p5", "hymba-1.5b-w8-p16", "hymba-1.5b-w8-p12"])
+def test_prefill_then_decode_continues(arch, window, P):
+    """A decode step after a prefill of ``P`` tokens, through
+    ``splice_prefill``, gives the logits of the full forward pass: GQA
+    and MLA rows, SSM state, and a sliding window's ring, whether the
+    prompt fills part of it, all of it, or wraps it (P % window != 0)."""
+    from repro.configs import replace
+    cfg = get_reduced(arch)
+    if cfg.num_experts:       # continuous gating, as in the tests above
+        cfg = replace(cfg, top_k=cfg.num_experts)
+    if window is not None:
+        cfg = replace(cfg, window=window)
     params, _ = init_stack(KEY, cfg)
-    B, S = 2, 32
-    tokens = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
-    last, pcache = prefill(params, tokens[:, :S], cfg)
-    cache = splice(init_cache(cfg, B, max_len=S + 8), pcache)
-    logits, _ = decode_step(params, cache, tokens[:, S],
-                            jnp.full((B,), S, jnp.int32), cfg)
+    B = 2
+    tokens = jax.random.randint(KEY, (B, P + 1), 0, cfg.vocab_size)
+    _, pcache = prefill(params, tokens[:, :P], cfg)
+    cache = splice_prefill(init_cache(cfg, B, max_len=P + 8), pcache)
+    logits, _ = decode_step(params, cache, tokens[:, P],
+                            jnp.full((B,), P, jnp.int32), cfg)
     full_logits, _ = forward(params, tokens, cfg)
-    a = np.asarray(full_logits[:, S], np.float32)
+    a = np.asarray(full_logits[:, P], np.float32)
     b = np.asarray(logits, np.float32)
     assert np.abs(a - b).max() / max(np.abs(a).max(), 1.0) < 0.05
 
@@ -245,7 +253,7 @@ def test_served_tokens_match_a_plain_greedy_loop(argv, tmp_path, monkeypatch):
     want = []
     with jax.set_mesh(make_local_mesh(1, 1)):
         logits, pcache = prefill_jit(params, prompts)
-        cache = splice(init_cache(cfg, B, max_len=P + G), pcache)
+        cache = splice_prefill(init_cache(cfg, B, max_len=P + G), pcache)
         tok = pick(logits, jnp.zeros((B,), jnp.int32))
         assert np.array_equal(tok, out["first_token"])
         cur = jnp.full((B,), P, jnp.int32)

@@ -66,14 +66,3 @@ def test_reg_mode_resolution():
     assert resolve_reg_mode(RegMode.PRE_MR, 300, kernel_space=True,
                             crossover_pages=1) == RegMode.PRE_MR
 
-
-def test_optimized_knobs_only_confirmed():
-    from repro.configs.optimized import DEFAULT_ON, optimize
-    assert "flash_bf16" not in DEFAULT_ON          # refuted in §Perf
-    assert "ssd_chunk" not in DEFAULT_ON
-    cfg = get_config("qwen2-moe-a2.7b")
-    opt = optimize(cfg)
-    assert opt.moe_shard_map and opt.attn_q_block == 1024
-    assert opt.ssm_chunk == cfg.ssm_chunk          # untouched
-    base = optimize(cfg, only=set())
-    assert base == cfg
