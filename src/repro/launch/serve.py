@@ -29,7 +29,7 @@ from repro import box, trace
 from repro.configs import get_config, get_reduced, replace
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import device_info, make_local_mesh
-from repro.models import (decode_step, init_cache, init_stack, prefill,
+from repro.models import (decode_step, init_cache, init_stack, moe, prefill,
                           splice_prefill)
 
 # pages reserved per client for the KV spill arena (the heap slice of
@@ -198,6 +198,13 @@ def run(argv: Optional[Sequence[str]] = None) -> Dict:
             if cfg.uses_moe:
                 compiling.add("moe.experts_held", cfg.experts_held)
                 compiling.add("moe.experts_routed", cfg.num_experts)
+                # the MoE layers of each program that run the held
+                # experts densely: a call's tokens decide (moe.dense_held)
+                group = B // prefill_groups(B, args.prompt_len)
+                for program, tokens in (("step", B),
+                                        ("prefill", group * args.prompt_len)):
+                    compiling.add(f"moe.dense_held.{program}", cfg.moe_layers
+                                  * moe.dense_held(tokens, cfg))
             if "mla" in cache:
                 compiling.add("mla.cache_bytes", sum(
                     a.nbytes for a in jax.tree.leaves(cache["mla"])))

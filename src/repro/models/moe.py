@@ -1,4 +1,4 @@
-"""Mixture-of-Experts with sort-based dropless dispatch.
+"""Mixture-of-Experts with dropless dispatch to the held experts.
 
 The router scores every expert of the layer (softmax, then greedy
 top-k; the gates are renormalized only where ``cfg.norm_topk_prob``
@@ -6,11 +6,20 @@ says so, then scaled by ``cfg.routed_scaling``). A layer holds the
 weights of ``cfg.experts_held`` consecutive experts from
 ``cfg.expert_offset`` on: all of them on one chip, or this chip's share
 where ``cfg.expert_parallel`` chips divide the layer. The assignments to
-held experts are sorted by expert into one buffer of
-T · min(top_k, held) rows, which holds every one of them, and each
-expert's rows go through its SwiGLU as one group of a ragged matmul: no
-token is dropped, and the FLOPs are those of the assignments. What
-experts held elsewhere would add is left to their chips.
+held experts go through their SwiGLUs in one of two forms, chosen by
+the call's token count T (``dense_held``); both drop no token:
+
+- sorted: the assignments are sorted by expert into one buffer of
+  T · min(top_k, held) rows, which holds every one of them, and each
+  expert's rows go through its SwiGLU as one group of a ragged matmul,
+  so the FLOPs are those of the assignments;
+- dense: where so few tokens come that reading the held experts'
+  weights bounds the layer, every token goes through every held expert
+  as batched matmuls over the stacked weights, weighted by its gate (0
+  where the expert is not among its top k), so each weight is read
+  once, where it lies.
+
+What experts held elsewhere would add is left to their chips.
 
 Shared experts are fused into one dense swiglu of width shared·moe_d_ff.
 """
@@ -63,15 +72,32 @@ def init_moe(key: jax.Array, cfg: ModelConfig, specs: SpecTree) -> Dict:
     return p
 
 
-def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Dropless dispatch of ``xt`` (T, M) to the ``E_loc`` held experts,
-    global ids ``cfg.expert_offset ..`` (EP slice). Returns (y (T, M) f32
-    partial, aux, held (T, E_loc) int32: how many of each token's top-k
-    went to each held expert, 0 or 1)."""
-    T, M = xt.shape
+# The bf16 FLOP:byte balance of a TPU v5e: 197 TFLOP/s over 819 GB/s
+# ≈ 240. Through every held expert, T tokens do 2·T FLOPs for each
+# 2-byte weight read, so at or below this many tokens the held experts'
+# matmuls are bound by reading their weights, whatever share of the
+# rows are assignments.
+WEIGHT_BOUND_TOKENS = 240
+
+
+def dense_held(tokens: int, cfg: ModelConfig) -> bool:
+    """Whether a call of ``tokens`` tokens runs the held experts in the
+    dense form: the weights bound the layer (``WEIGHT_BOUND_TOKENS``),
+    and the assignments expect at least one row for each expert
+    (tokens · top_k ≥ routed experts). A few tokens over many held
+    experts keep the sorted form, which skips the empty ones."""
+    return (tokens <= WEIGHT_BOUND_TOKENS
+            and tokens * cfg.top_k >= cfg.num_experts)
+
+
+def _route(xt: jax.Array, p: Dict, cfg: ModelConfig
+           ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The router over all ``cfg.num_experts``: (gate (T, K) f32, scaled
+    as the config says; local_e (T·K,), each assignment's expert less
+    ``cfg.expert_offset``; in_slice (T·K,), whether that expert is held
+    here; aux loss)."""
+    T = xt.shape[0]
     E, K = cfg.num_experts, cfg.top_k
-    E_loc = cfg.experts_held
 
     logits = jnp.einsum("tm,me->te", xt.astype(jnp.float32), p["router"])
     probs = jax.nn.softmax(logits, axis=-1)                       # (T, E)
@@ -85,9 +111,31 @@ def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig
     ce = jnp.zeros(E).at[expert_idx.reshape(-1)].add(1.0) / (T * K)
     aux = cfg.router_aux_weight * E * jnp.sum(me * ce)
 
+    local_e = expert_idx.reshape(-1) - cfg.expert_offset
+    in_slice = (local_e >= 0) & (local_e < cfg.experts_held)
+    return gate, local_e, in_slice, aux
+
+
+def _held_counts(local_e: jax.Array, in_slice: jax.Array,
+                 cfg: ModelConfig) -> jax.Array:
+    """(T, E_loc) int32 from the flat (T·K,) local ids: how many of each
+    token's top-k went to each held expert, 0 or 1."""
+    E_loc = cfg.experts_held
+    return jax.nn.one_hot(jnp.where(in_slice, local_e, -1), E_loc,
+                          dtype=jnp.int32).reshape(-1, cfg.top_k, E_loc).sum(1)
+
+
+def _dispatch_sorted(xt: jax.Array, p: Dict, cfg: ModelConfig
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless dispatch of ``xt`` (T, M) to the ``E_loc`` held experts,
+    global ids ``cfg.expert_offset ..`` (EP slice), sorted into ragged
+    matmuls. Returns (y (T, M) f32 partial, aux, held (T, E_loc) int32:
+    how many of each token's top-k went to each held expert, 0 or 1)."""
+    T, M = xt.shape
+    K, E_loc = cfg.top_k, cfg.experts_held
+    gate, local_e, in_slice, aux = _route(xt, p, cfg)
+
     # ---- sort the held assignments by expert: one row each ----
-    local_e = expert_idx.reshape(-1) - cfg.expert_offset          # (T*K,)
-    in_slice = (local_e >= 0) & (local_e < E_loc)
     flat_e = jnp.where(in_slice, local_e, E_loc)                  # E_loc = out
     rows = T * min(K, E_loc)              # a token sends ≤ min(K, E_loc) here
     order = jnp.argsort(flat_e, stable=True)[:rows]
@@ -103,9 +151,30 @@ def _dispatch_core(xt: jax.Array, p: Dict, cfg: ModelConfig
     w = jnp.where(held, gate.reshape(-1)[order], 0.0)
     y = jnp.zeros((T, M), jnp.float32).at[token_of].add(
         yg.astype(jnp.float32) * w[:, None])
-    counts = jax.nn.one_hot(jnp.where(in_slice, local_e, -1), E_loc,
-                            dtype=jnp.int32).reshape(T, K, E_loc).sum(1)
-    return y, aux, counts
+    return y, aux, _held_counts(local_e, in_slice, cfg)
+
+
+def _dispatch_dense(xt: jax.Array, p: Dict, cfg: ModelConfig
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The same layer as ``_dispatch_sorted``, with every token through
+    every held expert: batched matmuls over the stacked weights in their
+    stored layout (the held expert is the batch dimension), so no weight
+    is gathered or copied, combined in float32 with a (T, E_loc) gate
+    matrix that is 0 where a held expert is not among a token's top k."""
+    T, M = xt.shape
+    E_loc = cfg.experts_held
+    gate, local_e, in_slice, aux = _route(xt, p, cfg)
+
+    local = jnp.where(in_slice, local_e, -1).reshape(gate.shape)  # (T, K)
+    gates = jnp.sum(jnp.where(local[..., None] == jnp.arange(E_loc),
+                              gate[..., None], 0.0), 1)           # (T, E_loc)
+
+    xe = jnp.broadcast_to(xt, (E_loc, T, M))
+    h = jnp.einsum("etm,emf->etf", xe, p["wi"])
+    g = jnp.einsum("etm,emf->etf", xe, p["wg"])
+    yg = jnp.einsum("etf,efm->etm", h * jax.nn.silu(g), p["wo"])  # (E_loc, T, M)
+    y = jnp.sum(yg.astype(jnp.float32) * gates.T[:, :, None], 0)
+    return y, aux, _held_counts(local_e, in_slice, cfg)
 
 
 def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig
@@ -114,7 +183,8 @@ def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig
     token's assignments to each held expert)."""
     B, S, M = x.shape
     xt = x.reshape(B * S, M)
-    y, aux, held = _dispatch_core(xt, p, cfg)
+    dispatch = _dispatch_dense if dense_held(B * S, cfg) else _dispatch_sorted
+    y, aux, held = dispatch(xt, p, cfg)
     if cfg.num_shared_experts:
         y = y + swiglu(xt, p["shared_wi"], p["shared_wg"],
                        p["shared_wo"]).astype(jnp.float32)

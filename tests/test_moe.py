@@ -77,12 +77,31 @@ def test_expert_shares_sum_to_the_uncut_layer(ep):
     assert err < 2e-2, err
 
 
+# both forms of the held experts, each held to the same reference
+FORMS = {"sorted": moe_mod._dispatch_sorted, "dense": moe_mod._dispatch_dense}
+
+
+def dispatch(form, p, x, cfg):
+    """One form's (y (T, M) f32 with the shared experts added, held
+    (B, S, E_loc)), called directly, whatever form ``moe_apply`` would
+    choose; ``held`` is asserted equal to the other form's, bit for bit."""
+    xt = x.reshape(-1, cfg.d_model)
+    y, _, held = FORMS[form](xt, p, cfg)
+    y = y + swiglu(xt, p["shared_wi"], p["shared_wg"],
+                   p["shared_wo"]).astype(jnp.float32)
+    other = "dense" if form == "sorted" else "sorted"
+    assert np.array_equal(np.asarray(held),
+                          np.asarray(FORMS[other](xt, p, cfg)[2]))
+    return y, held.reshape(x.shape[:2] + (cfg.experts_held,))
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("ep", [1, 2])
-def test_dispatch_matches_every_token_through_every_held_expert(ep):
+def test_dispatch_matches_every_token_through_every_held_expert(ep, form):
     cfg = replace(get_reduced(ARCH), expert_parallel=ep)
     p = moe_params(cfg)
     x = tokens_in(cfg)
-    y, _, held = moe_mod.moe_apply(p, x, cfg)
+    y, held = dispatch(form, p, x, cfg)
     want = dense_moe(p, x, cfg)
     got = y.reshape(want.shape).astype(jnp.float32)
     assert float(jnp.abs(got - want).max() / jnp.abs(want).max()) < 1e-2
@@ -92,7 +111,8 @@ def test_dispatch_matches_every_token_through_every_held_expert(ep):
         assert np.all(np.asarray(held.sum(-1)) == cfg.top_k)
 
 
-def test_no_token_is_dropped_when_routing_is_skewed_onto_one_expert():
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_no_token_is_dropped_when_routing_is_skewed_onto_one_expert(form):
     """A router that sends every token to held expert 0 (and one more):
     each of the 48 tokens still gets expert 0's output, which a capacity
     of a few tokens an expert would have dropped."""
@@ -101,11 +121,36 @@ def test_no_token_is_dropped_when_routing_is_skewed_onto_one_expert():
     first = cfg.expert_offset
     p["router"] = p["router"].at[:, first].add(50.0)
     x = jnp.abs(tokens_in(cfg))
-    y, _, held = moe_mod.moe_apply(p, x, cfg)
+    y, held = dispatch(form, p, x, cfg)
     assert np.all(np.asarray(held[..., 0]) == 1)          # all 48 tokens
     want = dense_moe(p, x, cfg)
     got = y.reshape(want.shape).astype(jnp.float32)
     assert float(jnp.abs(got - want).max() / jnp.abs(want).max()) < 1e-2
+
+
+def test_form_follows_the_token_count(monkeypatch):
+    """At most ``WEIGHT_BOUND_TOKENS`` tokens, enough to expect a row for
+    every expert, run densely; 2 × 320 tokens take the sorted form, and
+    agree with every token through every held expert."""
+    cfg = replace(get_reduced(ARCH), expert_parallel=2)
+    E, K = cfg.num_experts, cfg.top_k
+    assert moe_mod.dense_held(48, cfg) and moe_mod.dense_held(240, cfg)
+    assert not moe_mod.dense_held(241, cfg)
+    assert not moe_mod.dense_held(-(-E // K) - 1, cfg)
+    assert moe_mod.dense_held(-(-E // K), cfg)
+    taken = []
+    for form in ("sorted", "dense"):
+        monkeypatch.setattr(moe_mod, f"_dispatch_{form}",
+                            lambda *a, f=FORMS[form]: taken.append(f) or f(*a))
+    p = moe_params(cfg)
+    x = tokens_in(cfg, n=320)
+    y, _, _ = moe_mod.moe_apply(p, x, cfg)
+    assert taken == [FORMS["sorted"]]
+    want = dense_moe(p, x, cfg)
+    got = y.reshape(want.shape).astype(jnp.float32)
+    assert float(jnp.abs(got - want).max() / jnp.abs(want).max()) < 1e-2
+    moe_mod.moe_apply(p, tokens_in(cfg), cfg)
+    assert taken == [FORMS["sorted"], FORMS["dense"]]
 
 
 def test_gates_are_not_renormalized_where_the_config_says_so():
@@ -206,6 +251,10 @@ def test_serving_job_counts_routing_and_the_latent_cache():
     assert c["mla.cache_bytes"] == cfg.num_layers * B * (P + G) * (
         cfg.kv_lora_rank + cfg.qk_rope_dim) * 2
     moe_layers = cfg.num_layers - cfg.first_k_dense
+    # a step's 3 tokens expect fewer than one row an expert (3 · 2 < 8):
+    # sorted; the prefill's 48 run densely
+    assert (c["moe.dense_held.step"], c["moe.dense_held.prefill"]) == (
+        0, moe_layers)
     for phase, tokens in (("prefill", B * P), ("decode", B * G)):
         n = c[f"moe.assign_held.{phase}"]
         assert 0 < n < moe_layers * tokens * cfg.top_k
@@ -298,3 +347,32 @@ def test_serving_step_updates_the_latent_cache_in_place():
     compiled = lowered.compile()
     assert compiled.memory_analysis().alias_size_in_bytes == sum(
         a.nbytes for a in leaves)
+
+
+def test_deepseek_cell_step_runs_dense_and_prefill_sorted():
+    """At the DeepSeek cell's token counts (a step of batch 64, a prefill
+    group of 8 × 1,024 tokens; 8 of 64 experts held, top-6) at reduced
+    width, lowered for the TPU from shapes as ``serve.run`` lowers them:
+    the step has no ragged matmul, the prefill has its three."""
+    from repro.launch import serve
+    from repro.launch.mesh import make_local_mesh
+    from repro.models import init_cache
+    cfg = replace(get_reduced(ARCH), num_experts=64, top_k=6,
+                  expert_parallel=8)
+    B, P, G = 64, 1024, 256
+    assert moe_mod.dense_held(B, cfg)
+    assert not moe_mod.dense_held(B // serve.prefill_groups(B, P) * P, cfg)
+    prefill_jit, step_jit, _ = serve.programs(cfg)
+    tpu = {"lowering_platforms": ("tpu",)}
+    with jax.set_mesh(make_local_mesh(1, 1)):
+        params = jax.eval_shape(lambda k: init_stack(k, cfg)[0],
+                                jax.random.key(0))
+        cache = jax.eval_shape(lambda: init_cache(cfg, B, max_len=P + G))
+        tok = jax.ShapeDtypeStruct((B,), jnp.int32)
+        step = step_jit.trace(params, cache, tok, tok).lower(**tpu)
+        group = prefill_jit.trace(
+            params, jax.ShapeDtypeStruct((B // 8, P), jnp.int32)).lower(**tpu)
+    assert serve.prefill_groups(B // 8, P) == 1
+    op = '"chlo.ragged_dot"('
+    assert op not in step.as_text()
+    assert group.as_text().count(op) == 3
